@@ -9,11 +9,11 @@ compute?
      micro-batch) with the `pipeline_overlap` term, swept over depth x
      exchange x batch. depth=1 is the strictly-serial schedule the
      pre-refactor step factories ran.
-  2. MEASURED: real serve-step wall clock on a virtual 8-device CPU mesh
-     (subprocess, like the distributed tests), same sweep. CPU collectives
-     are memcpys so the overlap itself is invisible here — this view checks
-     the pipelined step's overhead (slicing + k-fold smaller intermediates),
-     not the wire win.
+  2. MEASURED: real serve-step wall clock on the devices this process
+     holds (one chip owns one process, so the sweep never starts a child),
+     same sweep. On CPU devices the collectives are memcpys and the overlap
+     itself is invisible — there this view checks the pipelined step's
+     overhead (slicing + k-fold smaller intermediates), not the wire win.
 
   PYTHONPATH=src python -m benchmarks.bench_pipeline [--tiny]
 """
@@ -21,14 +21,10 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import os
 import statistics
-import subprocess
 import sys
 import time
 from typing import List, Optional
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CONFIGS = [
     # (registry name, row-wise exchange mode or None for table_wise)
@@ -92,10 +88,13 @@ def model_sweep(batches: List[int], depths: List[int], mode: str):
 
 
 # ---------------------------------------------------------------------------
-# Part 2: measured serve-step sweep (subprocess, 8 virtual CPU devices)
+# Part 2: measured serve-step sweep (in this process, its devices)
 # ---------------------------------------------------------------------------
-def measured_child(batches: List[int], depths: List[int], iters: int,
-                   rounds: int) -> int:
+def measured_sweep(batches: List[int], depths: List[int], iters: int,
+                   rounds: int) -> List[dict]:
+    """Times the serve step over every (config, exchange, batch, depth)
+    on ``jax.devices()``; prints one CSV row per timing and returns them
+    as dicts."""
     import jax
     import jax.numpy as jnp
     from repro.configs.registry import get_dlrm
@@ -106,8 +105,10 @@ def measured_child(batches: List[int], depths: List[int], iters: int,
 
     n = len(jax.devices())
     mesh = make_mesh((1, n), ("data", "model"))
-    print(f"# measured: serve step on {n} virtual CPU devices")
+    print(f"# measured: serve step on {n} {jax.devices()[0].platform} "
+          f"devices")
     print("config,exchange,batch,depth,t_step_ms,speedup_vs_serial,best")
+    rows = []
     for name, exch in CONFIGS:
         cfg = get_dlrm(name).reduced()
         exch_label = exch or "pooled_a2a"
@@ -140,36 +141,9 @@ def measured_child(batches: List[int], depths: List[int], iters: int,
                 speed = (t1 / t) if t1 else float("nan")
                 print(f"{name},{exch_label},{B},{k},{t*1e3:.2f},"
                       f"{speed:.2f}x,{'*' if k == best else ''}")
-    return 0
-
-
-def measured_sweep(batches: List[int], depths: List[int], iters: int,
-                   rounds: int, devices: int) -> List[dict]:
-    """Returns the child's CSV rows parsed back as dicts (one per
-    (config, exchange, batch, depth) timing)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(REPO, "src"), REPO, env.get("PYTHONPATH", "")])
-    cmd = [sys.executable, "-m", "benchmarks.bench_pipeline",
-           "--measured-child",
-           "--measured-batches", ",".join(map(str, batches)),
-           "--depths", ",".join(map(str, depths)),
-           "--iters", str(iters), "--rounds", str(rounds)]
-    proc = subprocess.run(cmd, env=env, cwd=REPO, capture_output=True,
-                          text=True, timeout=1800)
-    sys.stdout.write(proc.stdout)
-    if proc.returncode != 0:
-        sys.stderr.write(proc.stderr[-3000:])
-        raise RuntimeError("measured pipeline sweep failed")
-    rows = []
-    for line in proc.stdout.splitlines():
-        parts = line.strip().split(",")
-        if len(parts) == 7 and parts[2].isdigit() and parts[3].isdigit():
-            rows.append({"config": parts[0], "exchange": parts[1],
-                         "batch": int(parts[2]), "depth": int(parts[3]),
-                         "t_step_ms": float(parts[4]),
-                         "speedup": float(parts[5].rstrip("x"))})
+                rows.append({"config": name, "exchange": exch_label,
+                             "batch": B, "depth": k, "t_step_ms": t * 1e3,
+                             "speedup": speed})
     return rows
 
 
@@ -183,33 +157,27 @@ def main(argv: Optional[list] = None) -> int:
                     choices=["inference", "training"])
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--rounds", type=int, default=5)
-    ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--no-measure", action="store_true",
-                    help="model sweep only (no subprocess device timing)")
+                    help="model sweep only (no device timing)")
     ap.add_argument("--tiny", action="store_true",
                     help="CI-sized: small batch, fewer reps")
     ap.add_argument("--emit-json", action="store_true",
                     help="write BENCH_pipeline.json (claims + scalars)")
-    ap.add_argument("--measured-child", action="store_true",
-                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     batches = [int(b) for b in args.batches.split(",")]
     measured_batches = [int(b) for b in args.measured_batches.split(",")]
     depths = [int(d) for d in args.depths.split(",")]
     if args.tiny:
         measured_batches, depths = [64], [1, 2, 4]
-        args.iters, args.rounds, args.devices = 2, 3, 4
+        args.iters, args.rounds = 2, 3
         # big enough to amortize the per-micro-batch collective latency —
         # the regime where the planner actually picks depth > 1
         batches = [4096]
-    if args.measured_child:
-        return measured_child(measured_batches, depths, args.iters,
-                              args.rounds)
     ok, top = model_sweep(batches, depths, args.mode)
     measured = []
     if not args.no_measure:
         measured = measured_sweep(measured_batches, depths, args.iters,
-                                  args.rounds, args.devices)
+                                  args.rounds)
     if args.emit_json:
         from benchmarks._artifacts import write_bench_json
         claims = [("model_overlap", ok,
@@ -225,7 +193,7 @@ def main(argv: Optional[list] = None) -> int:
             meas_ok = bool(deep) and worst >= 0.5
             claims.append((
                 "measured_overhead", meas_ok,
-                f"real serve-step on virtual CPU devices: {len(deep)} "
+                f"real serve-step on this process's devices: {len(deep)} "
                 f"pipelined timings collected, worst depth>1 speedup "
                 f"{worst:.2f}x >= 0.5x (slicing overhead bounded; CPU "
                 f"collectives hide no wire time)"))

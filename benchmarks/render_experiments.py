@@ -1,4 +1,4 @@
-"""Render the EXPERIMENTS.md §Roofline tables from dry-run reports
+"""Render the roofline tables (markdown) from dry-run reports
 (baseline + optimized side by side)."""
 import glob
 import json

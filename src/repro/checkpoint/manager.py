@@ -1,6 +1,6 @@
 """Fault-tolerant checkpointing: atomic step-tagged snapshots + async writer.
 
-Requirements at 1000+ nodes (DESIGN.md):
+Requirements at 1000+ nodes:
   * ATOMIC: a checkpoint is visible only when complete. Writes land in
     ``step_NNNNNNNN.tmp-<pid>`` and are ``os.rename``d (atomic on POSIX)
     to ``step_NNNNNNNN`` last — a job killed mid-write never leaves a

@@ -8,7 +8,9 @@ correctness property of the repo (tests/test_dlrm_distributed.py).
 Layout conventions:
   dense features : (B, num_dense) float
   sparse indices : (B, T, L) int32      T = num_tables, L = lookups/table
-  tables         : (T, R, d) float      stacked (RM2 tables are homogeneous)
+  tables         : (T, R, d) float      stacked (RM2 tables are homogeneous);
+                                        placed on a TPU as lane-dense lines
+                                        (T, R/p, p*d) — `core/table_layout.py`
   pooled         : (B, T, d) float      sum-pooling (paper default)
 """
 from __future__ import annotations
@@ -20,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import DLRMConfig
+from repro.core.table_layout import gather_rows, scatter_add_rows
 
 Params = Dict[str, object]
 
@@ -71,11 +74,15 @@ def mlp_forward(layers: List[Dict[str, jax.Array]], x: jax.Array,
     return x
 
 
-def embedding_bag(tables: jax.Array, indices: jax.Array) -> jax.Array:
-    """Lookup + sum-pool. tables (T,R,d), indices (B,T,L) -> (B,T,d)."""
-    # vmap over tables: for table t, rows (R,d)[idx (B,L)] -> (B,L,d)
-    def one_table(tab, idx):          # (R,d), (B,L)
-        return jnp.take(tab, idx, axis=0).sum(axis=1)  # (B,d)
+def embedding_bag(tables: jax.Array, indices: jax.Array,
+                  d: Optional[int] = None) -> jax.Array:
+    """Lookup + sum-pool. tables (T,R,d) — or lines (T,R/p,p*d) with the
+    row width ``d`` given — indices (B,T,L) -> (B,T,d)."""
+    d = d or tables.shape[-1]
+
+    # vmap over tables: for table t, rows of tab[idx (B,L)] -> (B,L,d)
+    def one_table(tab, idx):
+        return gather_rows(tab, idx, d).sum(axis=1)    # (B,d)
     out = jax.vmap(one_table, in_axes=(0, 1), out_axes=1)(tables, indices)
     return out                          # (B,T,d)
 
@@ -100,7 +107,7 @@ def dlrm_forward(params: Params, dense: jax.Array, indices: jax.Array,
                  cfg: DLRMConfig) -> jax.Array:
     """Full single-device forward (Alg. 1, n=1). Returns logits (B,)."""
     bot = mlp_forward(params["bot_mlp"], dense)                 # (B, d)
-    pooled = embedding_bag(params["tables"], indices)           # (B, T, d)
+    pooled = embedding_bag(params["tables"], indices, cfg.embed_dim)
     z = feature_interactions(bot, pooled)                       # (B, top_in)
     logits = mlp_forward(params["top_mlp"], z)[:, 0]            # (B,)
     return logits
@@ -147,7 +154,7 @@ def reference_train_step(params: Params, dense: jax.Array, indices: jax.Array,
             {**params, **dense_params}, dense, pooled)
         return bce_loss(logits, labels)
 
-    pooled = embedding_bag(params["tables"], indices)
+    pooled = embedding_bag(params["tables"], indices, cfg.embed_dim)
     dense_params = {"bot_mlp": params["bot_mlp"], "top_mlp": params["top_mlp"]}
     grads, g_pooled = jax.grad(dense_loss, argnums=(0, 1))(dense_params, pooled)
 
@@ -163,7 +170,7 @@ def reference_train_step(params: Params, dense: jax.Array, indices: jax.Array,
     flat_g = g_rows.transpose(1, 0, 2, 3).reshape(T, B * L, -1)      # (T, B*L, d)
 
     def upd(tab, idx, g):
-        return tab.at[idx].add(-lr * g)
+        return scatter_add_rows(tab, idx, -lr * g)
     tables = jax.vmap(upd)(tables, flat_idx, flat_g)
 
     loss = dense_loss(dense_params, pooled)

@@ -113,7 +113,7 @@ def sweep_system(latency_s: float, bandwidth: float, n_chips: int = 8) -> System
 
 
 def tpu_v5e_system(n_chips: int = 256) -> SystemConfig:
-    """TPU v5e adaptation target (DESIGN.md): 2D torus ICI, ~100 GB/s/chip
+    """TPU v5e adaptation target: 2D torus ICI, ~100 GB/s/chip
     aggregate injection, ~1 us/hop latency, 197 bf16 TFLOP/s, 16 GB HBM."""
     side = max(1, int(round(math.sqrt(n_chips))))
     a2a = Interconnect(100e9, 1e-6 * max(1, side // 2), Topology.TORUS_2D)

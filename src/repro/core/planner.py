@@ -15,7 +15,7 @@ chooses, per the generalized-roofline perf model (core/perf_model.py):
   * table placement : hot tables -> fast memory tier ("HBM-like": replicated
                       or table-wise near compute), cold -> bulk tier
                       (row-sharded across the mesh) — the TPU adaptation of
-                      the paper's hybrid HBM+DDR4 memory (DESIGN.md §1).
+                      the paper's hybrid HBM+DDR4 memory.
 
 The hot/cold split takes per-table access frequencies (from data stats or a
 profile pass) and greedily fills the fast tier by access-per-byte density —

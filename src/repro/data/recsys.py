@@ -1,6 +1,6 @@
 """Synthetic Criteo-like click-log pipeline for DLRM.
 
-Design requirements (DESIGN.md fault-tolerance story):
+Design requirements (the fault-tolerance story):
 
   * STATELESS and STEP-INDEXED: batch(step) is a pure function of
     (seed, step), so a restarted or re-sharded job regenerates exactly the

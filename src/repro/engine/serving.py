@@ -172,9 +172,11 @@ class ServeSession:
                              else "composed")
         self._steps: Dict[int, Callable] = {}
         self._depth_by_samples: Dict[int, int] = {}
-        if params is None:
-            params = dlrm_lib.init_dlrm(jax.random.PRNGKey(seed), cfg)
-        elif self._exchange_inst is None and "tables" not in params:
+        key = jax.random.PRNGKey(seed)
+        if params is None and self._exchange_inst is not None:
+            params = dlrm_lib.init_dlrm(key, cfg)
+        elif params is not None and self._exchange_inst is None \
+                and "tables" not in params:
             # plan-split params (e.g. TrainSession.params under plan=auto):
             # only accepted when the split matches THIS session's plan
             # groups, otherwise tables would land in the wrong tier.
@@ -194,9 +196,14 @@ class ServeSession:
                     f"merge_dlrm_params_by_plan under their own plan first")
         prepared = (self._exchange_inst.init_session_params(params, mesh)
                     if self._exchange_inst is not None else None)
-        self.params = (prepared if prepared is not None else
-                       parallel.shard_dlrm_params(params, cfg, mesh, axis,
-                                                  plan=plan))
+        if prepared is not None:
+            self.params = prepared
+        elif params is None:
+            self.params = parallel.init_dlrm_params(key, cfg, mesh, axis,
+                                                    plan=plan)
+        else:
+            self.params = parallel.shard_dlrm_params(params, cfg, mesh, axis,
+                                                     plan=plan)
         self.batcher = MicroBatcher(self.max_batch_queries, max_wait_ms / 1e3)
         self._qid = 0
         self._compiled: set = set()

@@ -103,17 +103,17 @@ class TrainSession(_SessionBase):
             optimizer=optimizer, plan=plan, dp_axes=dp_axes,
             pipeline_depth=self.pipeline_depth,
             compress_grads=compress_grads)
-        params = dlrm_lib.init_dlrm(jax.random.PRNGKey(seed), cfg)
+        key = jax.random.PRNGKey(seed)
         # an EmbeddingExchange instance with session state (hoststore):
         # its hooks own param placement and bracket every step below
         exch_inst = self.exchange_inst = (
             exchange if isinstance(exchange, parallel.EmbeddingExchange)
             else None)
-        prepared = (exch_inst.init_session_params(params, mesh)
-                    if exch_inst is not None else None)
+        prepared = (exch_inst.init_session_params(
+            dlrm_lib.init_dlrm(key, cfg), mesh)
+            if exch_inst is not None else None)
         params = (prepared if prepared is not None else
-                  parallel.shard_dlrm_params(params, cfg, mesh, axis,
-                                             plan=plan))
+                  parallel.init_dlrm_params(key, cfg, mesh, axis, plan=plan))
         opt_state = parallel.init_dlrm_opt_state(
             cfg, optimizer, plan, n_embed, compress_grads=compress_grads,
             n_devices=n_full)

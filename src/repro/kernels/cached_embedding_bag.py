@@ -12,12 +12,11 @@ and a bulk DDR4-like tier). The runtime layout (`core/tiered_embedding.py`):
 The index stream is pre-translated (CacheEmbedding's `prepare_ids` idea,
 hpcaitech/CacheEmbedding): for each lookup either ``fast_idx`` holds the hot
 slot and ``bulk_idx`` the pad row, or vice versa. The kernel then needs NO
-per-element branching: every grid step DMAs one row from each tier and
-accumulates their sum — exactly one of the two is the zero pad, so the pool
-is exact. Both index arrays ride the scalar-prefetch path (SMEM) so each
-step's BlockSpec ``index_map`` can steer the next row DMA, pipelining
-fast-tier and bulk-tier fetches back-to-back like the single-tier gather in
-``embedding_bag.py``.
+per-element branching: every lookup DMAs one line from each tier and adds
+their rows — exactly one of the two is the zero pad, so the pool is exact.
+Gather and pooling are `embedding_bag.gather_pool`; each tier keeps its own
+line layout, which is lane-dense on TPU only when its row count (S+1, R+1)
+is a multiple of ``128 // d``.
 """
 from __future__ import annotations
 
@@ -26,21 +25,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.embedding_bag import (Part, flat_indices, gather_pool,
+                                         index_spec, line_scratch,
+                                         lines_for_kernel)
 
 
-def _cached_bag_kernel(fast_idx_ref, bulk_idx_ref, fast_row_ref, bulk_row_ref,
-                       out_ref):
-    """One grid step: accumulate one fast-tier + one bulk-tier row (one of
-    the two is a zero pad row) into the (1, 1, d) output block."""
-    l = pl.program_id(2)
+def _cached_bag_kernel(fi_ref, bi_ref, fast_ref, bulk_ref, out_ref,
+                       fbuf, bbuf, sem, *, T, L, d, pf, pb):
+    def emit(t, pooled):
+        out_ref[pl.ds(t, 1), :] = pooled
 
-    @pl.when(l == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += (fast_row_ref[...].astype(out_ref.dtype)
-                     + bulk_row_ref[...].astype(out_ref.dtype))
+    gather_pool([Part(fast_ref, fi_ref, 0, T, pf),
+                 Part(bulk_ref, bi_ref, 0, T, pb)], [fbuf, bbuf], sem,
+                T=T, L=L, d=d, emit=emit)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -50,7 +48,7 @@ def cached_embedding_bag_pallas(fast: jax.Array, bulk: jax.Array,
     """fast (T, S+1, d), bulk (T, R+1, d) any float dtype; fast_idx/bulk_idx
     (B, T, L) int32 pre-translated slots -> pooled (B, T, d) fp32.
 
-    ``interpret=True`` executes the kernel body in Python on CPU (validation
+    ``interpret=True`` executes the kernel body on the host (validation
     mode); on TPU pass ``interpret=False``.
     """
     T, S1, d = fast.shape
@@ -58,19 +56,19 @@ def cached_embedding_bag_pallas(fast: jax.Array, bulk: jax.Array,
     B, T3, L = fast_idx.shape
     assert T == T2 == T3 and d == d2, (fast.shape, bulk.shape, fast_idx.shape)
     assert fast_idx.shape == bulk_idx.shape
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, T, L),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, t, l, fi, bi: (t, fi[b, t, l], 0)),
-            pl.BlockSpec((1, 1, d), lambda b, t, l, fi, bi: (t, bi[b, t, l], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, t, l, fi, bi: (b, t, 0)),
-    )
+    name = "cached_embedding_bag_pallas"
+    fast_l, pf = lines_for_kernel(name, fast, interpret)
+    bulk_l, pb = lines_for_kernel(name, bulk, interpret)
     return pl.pallas_call(
-        _cached_bag_kernel,
-        grid_spec=grid_spec,
+        functools.partial(_cached_bag_kernel, T=T, L=L, d=d, pf=pf, pb=pb),
+        grid=(B,),
+        in_specs=[index_spec(T, L, lambda b: b),
+                  index_spec(T, L, lambda b: b),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, T, d), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), jnp.float32),
+        scratch_shapes=line_scratch(
+            [(pf * d, fast.dtype), (pb * d, bulk.dtype)], L),
         interpret=interpret,
-    )(fast_idx, bulk_idx, fast, bulk)
+    )(flat_indices(fast_idx), flat_indices(bulk_idx), fast_l, bulk_l)

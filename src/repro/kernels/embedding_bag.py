@@ -2,43 +2,182 @@
 
 Paper context (Sec. IV-D-2): embedding lookups are scattered 64-256 B reads
 with no spatial locality; throughput is bound by the memory system's random
-access rate, not FLOPs. The TPU-native adaptation (DESIGN.md) is a
-*scalar-prefetch gather*: lookup indices are prefetched into SMEM before the
-kernel body runs, so each grid step's BlockSpec ``index_map`` can select
-WHICH table row the next DMA brings HBM→VMEM. The DMA engine then pipelines
-row fetches back-to-back — the structural analogue of the paper's
-"near-memory pooling" (rows are summed in VMEM; only the pooled vector is
-ever written back / crosses ICI).
+access rate, not FLOPs. The kernel gathers with manual DMAs: the table stays
+in HBM (``memory_space=ANY``) in its lane-dense line layout
+(`repro.core.table_layout`), and each lookup copies the one 128-lane line
+that holds its row into VMEM, where the row's d lanes are picked out and
+summed. Only pooled vectors are ever written back (the structural analogue
+of the paper's "near-memory pooling").
 
-Grid layout: ``(B, T, L)`` — one looked-up row per step, innermost over L so
-the (1, 1, d) output block stays resident in VMEM while L rows accumulate
-into it (Pallas keeps an output block live across consecutive grid steps
-that map to the same block).
+Grid: one step per sample. The step's ``T*L`` indices arrive as their own
+SMEM block (``(1, T*L)`` int32, 12.8 KB at RM2's T=40, L=80), so SMEM holds
+one sample's indices whatever the batch. Within a step the tables are
+walked in order with two VMEM line buffers: the DMAs for table t+1 are in
+flight while table t is pooled. Rows are added in L order, one at a time,
+into an fp32 accumulator.
 
-Alignment note: the natural TPU lane width is 128; d=32 (RM2-small, 64 B
-rows) under-fills a lane vector exactly as 64 B reads under-fill a DRAM
-burst — the kernel is still correct, and the ``memsys`` model quantifies the
-efficiency loss on the DRAM side.
+The same gather/pool routine (`gather_pool`) serves the cached and fused
+kernels (``cached_embedding_bag.py``, ``fused_serve.py``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.table_layout import LANES, rows_per_line, to_lines
 
-def _embedding_bag_kernel(idx_ref, row_ref, out_ref):
-    """One grid step: accumulate one (1, 1, d) row into the output block."""
-    l = pl.program_id(2)
 
-    @pl.when(l == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+class Part(NamedTuple):
+    """One table group a kernel gathers from.
 
-    out_ref[...] += row_ref[...].astype(out_ref.dtype)
+    ``tab``: HBM ref (T_k, R_k/p, p*d); ``idx``: SMEM ref (1, T*L) holding
+    this sample's row ids for every table; tables ``lo <= t < hi`` are
+    served by this part, table t at ``tab[t - lo]``."""
+
+    tab: object
+    idx: object
+    lo: int
+    hi: int
+    p: int
+
+
+def lines_for_kernel(name: str, tables: jax.Array, interpret: bool):
+    """(T, R, d) -> ((T, R/p, p*d) lines, p). Natively the line must span
+    the 128 lanes: the TPU cannot DMA a narrower slice."""
+    _, r, d = tables.shape
+    p = rows_per_line(d, r)
+    if not interpret and (p * d) % LANES:
+        raise ValueError(
+            f"{name}: a table of {r} rows of width {d} has no lane-dense "
+            f"line layout ({p * d} lanes per line; need a multiple of "
+            f"{LANES}). Use d dividing 128 with rows a multiple of "
+            f"{LANES // d if d < LANES and LANES % d == 0 else 1}, or d a "
+            f"multiple of 128.")
+    return to_lines(tables, p), p
+
+
+def index_spec(T: int, L: int, sample: Callable) -> pl.BlockSpec:
+    """One sample's (1, T*L) indices per grid step, in SMEM. The index
+    array is passed as `flat_indices` gives it."""
+    return pl.BlockSpec((None, 1, T * L),
+                        lambda *g: (sample(*g), 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def flat_indices(idx: jax.Array) -> jax.Array:
+    """(B, T, L) -> (B, 1, T*L): one SMEM row of indices per sample."""
+    B, T, L = idx.shape
+    return idx.reshape(B, 1, T * L)
+
+
+def line_scratch(parts_wd: Sequence[tuple], L: int):
+    """Two L-line VMEM buffers per part (``(width, dtype)`` each) and one
+    DMA semaphore per buffer slot."""
+    return ([pltpu.VMEM((2, L, w), dt) for w, dt in parts_wd]
+            + [pltpu.SemaphoreType.DMA((2,))])
+
+
+def _row(line: jax.Array, slot, d: int, p: int) -> jax.Array:
+    """(1, p*d) fp32 line -> (1, d) row in lanes [slot*d, slot*d + d).
+
+    The other rows' lanes are zeroed and the p lane groups folded onto
+    lanes [0, d) with rotations; exactly one group is non-zero, so the
+    fold adds exact zeros."""
+    if p == 1:
+        return line
+    group = jax.lax.broadcasted_iota(jnp.int32, line.shape, 1) // d
+    m = jnp.where(group == slot, line, 0.0)
+    folded = m
+    for q in range(1, p):
+        folded = folded + pltpu.roll(m, shift=q * d, axis=1)
+    return folded[:, :d]
+
+
+def gather_pool(parts: Sequence[Part], bufs, sem, *, T: int, L: int, d: int,
+                emit: Callable) -> None:
+    """Pool every table of one sample: ``emit(t, pooled (1, d) fp32)``.
+
+    Either every part serves every table (the cached layout: one row per
+    part, summed, exactly one of them a zero pad), or the parts split the
+    tables between them (the grouped layout)."""
+    summed = all(pt.lo == 0 and pt.hi == T for pt in parts)
+
+    def each_part(t, fn):
+        for k, pt in enumerate(parts):
+            if pt.lo == 0 and pt.hi == T:
+                fn(k, pt)
+            else:
+                pl.when(jnp.logical_and(t >= pt.lo, t < pt.hi))(
+                    functools.partial(fn, k, pt))
+
+    def start(t, slot):
+        def one(k, pt):
+            def body(l, c):
+                r = pt.idx[0, t * L + l]
+                pltpu.make_async_copy(
+                    pt.tab.at[t - pt.lo, pl.ds(r // pt.p, 1), :],
+                    bufs[k].at[slot, pl.ds(l, 1), :], sem.at[slot]).start()
+                return c
+            jax.lax.fori_loop(0, L, body, 0)
+        each_part(t, one)
+
+    def wait(t, slot):
+        def one(k, pt):
+            def body(l, c):
+                pltpu.make_async_copy(
+                    pt.tab.at[0, pl.ds(0, 1), :],
+                    bufs[k].at[slot, pl.ds(0, 1), :], sem.at[slot]).wait()
+                return c
+            jax.lax.fori_loop(0, L, body, 0)
+        each_part(t, one)
+
+    def pool(t, slot):
+        def body(l, acc):
+            rows = []
+            for k, pt in enumerate(parts):
+                r = pt.idx[0, t * L + l]
+                line = bufs[k][slot, pl.ds(l, 1), :].astype(jnp.float32)
+                rows.append(_row(line, r % pt.p, d, pt.p))
+            if summed:
+                row = rows[0]
+                for x in rows[1:]:
+                    row = row + x
+            else:
+                row = rows[-1]
+                for pt, x in zip(parts[-2::-1], rows[-2::-1]):
+                    row = jnp.where(t < pt.hi, x, row)
+            return acc + row
+        return jax.lax.fori_loop(0, L, body,
+                                 jnp.zeros((1, d), jnp.float32))
+
+    start(0, 0)
+
+    def table(t, c):
+        slot = t % 2
+
+        @pl.when(t + 1 < T)
+        def _prefetch():
+            start(t + 1, 1 - slot)
+
+        wait(t, slot)
+        emit(t, pool(t, slot))
+        return c
+
+    jax.lax.fori_loop(0, T, table, 0)
+
+
+def _embedding_bag_kernel(idx_ref, tab_ref, out_ref, buf, sem, *, T, L, d,
+                          p):
+    def emit(t, pooled):
+        out_ref[pl.ds(t, 1), :] = pooled
+
+    gather_pool([Part(tab_ref, idx_ref, 0, T, p)], [buf], sem, T=T, L=L,
+                d=d, emit=emit)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -46,99 +185,22 @@ def embedding_bag_pallas(tables: jax.Array, indices: jax.Array,
                          *, interpret: bool = True) -> jax.Array:
     """tables (T, R, d) any float dtype; indices (B, T, L) int32 -> (B, T, d) fp32.
 
-    ``interpret=True`` executes the kernel body in Python on CPU (validation
-    mode); on TPU pass ``interpret=False``.
+    ``interpret=True`` executes the kernel body on the host (validation
+    mode); on TPU pass ``interpret=False``. Pass tables that are stored as
+    lines and viewed as rows (`table_layout.to_rows`): the kernel's own
+    reshape back to lines then cancels and the table is not copied.
     """
     T, R, d = tables.shape
     B, T2, L = indices.shape
     assert T == T2, (tables.shape, indices.shape)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, T, L),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, t, l, idx: (t, idx[b, t, l], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, t, l, idx: (b, t, 0)),
-    )
+    lines, p = lines_for_kernel("embedding_bag_pallas", tables, interpret)
     return pl.pallas_call(
-        _embedding_bag_kernel,
-        grid_spec=grid_spec,
+        functools.partial(_embedding_bag_kernel, T=T, L=L, d=d, p=p),
+        grid=(B,),
+        in_specs=[index_spec(T, L, lambda b: b),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, T, d), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), jnp.float32),
+        scratch_shapes=line_scratch([(p * d, tables.dtype)], L),
         interpret=interpret,
-    )(indices, tables)
-
-
-# ---------------------------------------------------------------------------
-# Blocked variant: pool a whole L-block per grid step (fewer, larger DMAs).
-# The row gather becomes a VMEM-local take over an L-row scratch strip the
-# scalar-prefetched indices selected. Used when L is large and rows are
-# small (RM2: L=80, 64 B rows) so per-row DMA issue overhead dominates.
-# ---------------------------------------------------------------------------
-def _embedding_bag_rowblock_kernel(idx_ref, rows_ref, out_ref, *, lblk: int):
-    l = pl.program_id(2)
-
-    @pl.when(l == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    # rows_ref: (1, lblk, d) — lblk rows DMA'd this step, already selected by
-    # the index_map; sum them locally (associativity of sum pooling).
-    out_ref[...] += rows_ref[...].sum(axis=1, keepdims=True).astype(out_ref.dtype)
-
-
-def blocked_stream_aligned(indices: jax.Array, lblk: int) -> jax.Array:
-    """Traced predicate: every L-block of ``lblk`` lookups covers exactly the
-    consecutive rows [k*lblk, (k+1)*lblk) for some k.
-
-    This is the precondition under which the blocked kernel's
-    ``idx[b, t, l*lblk] // lblk`` row-block selection is exact; any other
-    stream (unsorted, non-aligned base, gaps) silently pools the WRONG rows.
-    """
-    B, T, L = indices.shape
-    blocks = indices.reshape(B, T, L // lblk, lblk)
-    base = blocks[..., :1]                               # (B, T, L/lblk, 1)
-    expect = base + jnp.arange(lblk, dtype=indices.dtype)
-    return jnp.logical_and((base[..., 0] % lblk == 0).all(),
-                           (blocks == expect).all())
-
-
-@functools.partial(jax.jit, static_argnames=("lblk", "interpret"))
-def embedding_bag_pallas_blocked(tables: jax.Array, indices: jax.Array,
-                                 *, lblk: int = 8, interpret: bool = True
-                                 ) -> jax.Array:
-    """Variant that fetches ``lblk`` CONSECUTIVE-SLOT rows per DMA.
-
-    The blocked row fetch is only exact when lookups within each L-block hit
-    consecutive lblk-aligned table rows (sorted/batched index streams); the
-    stream is checked at runtime and any misaligned batch falls back to the
-    per-row kernel (``embedding_bag_pallas``) instead of silently pooling
-    wrong rows.
-    """
-    T, R, d = tables.shape
-    B, T2, L = indices.shape
-    assert L % lblk == 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, T, L // lblk),
-        in_specs=[
-            pl.BlockSpec((1, lblk, d),
-                         lambda b, t, l, idx: (t, idx[b, t, l * lblk] // lblk, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, t, l, idx: (b, t, 0)),
-    )
-
-    def blocked(tab, idx):
-        return pl.pallas_call(
-            functools.partial(_embedding_bag_rowblock_kernel, lblk=lblk),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, T, d), jnp.float32),
-            interpret=interpret,
-        )(idx, tab)
-
-    def per_row(tab, idx):
-        return embedding_bag_pallas(tab, idx, interpret=interpret)
-
-    return jax.lax.cond(blocked_stream_aligned(indices, lblk),
-                        blocked, per_row, tables, indices)
+    )(flat_indices(indices), lines)

@@ -10,11 +10,11 @@ block is small enough to stay resident in VMEM for a whole batch block.
 
 ``fused_bag_interactions`` fuses gather -> pool -> A·Aᵀ:
 
-  grid (nB, bb, T, L) — batch blocks of ``block_b`` samples; within a block
-  one looked-up row per step (the same scalar-prefetch index stream the bag
-  kernels use steers each row DMA). A VMEM scratch accumulator
-  ``(bb, T+1, d)`` holds the bottom-MLP output (slot 0) and the running bag
-  pools (slots 1..T); at the last step of each batch block the resident
+  grid (nB, bb) — batch blocks of ``block_b`` samples, one sample per step;
+  the step gathers and pools its T bags with the bag kernels' manual-DMA
+  routine (``embedding_bag.gather_pool``). A VMEM scratch accumulator
+  ``(bb, T+1, d)`` holds the bottom-MLP output (slot 0) and the bag pools
+  (slots 1..T); at the last step of each batch block the resident
   accumulator feeds the batched ``A·Aᵀ`` contraction directly — the pooled
   embeddings never touch HBM and the whole pipeline is one kernel launch.
 
@@ -56,6 +56,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.embedding_bag import (Part, flat_indices, gather_pool,
+                                         index_spec, line_scratch,
+                                         lines_for_kernel)
+
 
 # ---------------------------------------------------------------------------
 # Shared pieces
@@ -92,28 +96,20 @@ def _finalize(bot_out: jax.Array, f: jax.Array,
         [bot_out.astype(jnp.float32), f[:B, li, lj]], axis=1)
 
 
-def _fused_kernel_body(bot_ref, row_sum, acc_ref, out_ref, *, bb, T, L):
-    """The per-step accumulate/contract shared by every variant.
+def _fused_kernel_body(j, bot_ref, out_ref, acc_ref, parts, bufs, sem, *,
+                       bb, T, L, d):
+    """One sample of a batch block: bottom-MLP output into accumulator
+    slot 0, the T bag pools into slots 1..T, and after the block's last
+    sample the batched ``A·Aᵀ`` contraction."""
+    acc_ref[pl.ds(j, 1), pl.ds(0, 1), :] = (
+        bot_ref[pl.ds(j, 1), :].astype(jnp.float32).reshape(1, 1, d))
 
-    ``row_sum`` is this step's (1, 1, d) contribution (one row, or the
-    fast+bulk pair already summed). Grid order is lexicographic with l
-    fastest, so (j==0, t==0, l==0) opens a batch block and
-    (j==bb-1, t==T-1, l==L-1) closes it.
-    """
-    j = pl.program_id(1)
-    t = pl.program_id(2)
-    l = pl.program_id(3)
+    def emit(t, pooled):
+        acc_ref[pl.ds(j, 1), pl.ds(t + 1, 1), :] = pooled.reshape(1, 1, d)
 
-    @pl.when(jnp.logical_and(jnp.logical_and(j == 0, t == 0), l == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        acc_ref[:, 0, :] = bot_ref[...].astype(acc_ref.dtype)
+    gather_pool(parts, bufs, sem, T=T, L=L, d=d, emit=emit)
 
-    slot = (pl.ds(j, 1), pl.ds(t + 1, 1), slice(None))
-    pl.store(acc_ref, slot, pl.load(acc_ref, slot) + row_sum)
-
-    @pl.when(jnp.logical_and(jnp.logical_and(j == bb - 1, t == T - 1),
-                             l == L - 1))
+    @pl.when(j == bb - 1)
     def _contract():
         a = acc_ref[...]                              # (bb, s1, d) fp32
         out_ref[...] = jax.lax.dot_general(
@@ -121,13 +117,32 @@ def _fused_kernel_body(bot_ref, row_sum, acc_ref, out_ref, *, bb, T, L):
             preferred_element_type=jnp.float32)
 
 
+def _fused_call(kernel, *, bb, Bp, T, L, d, s1, n_idx, n_tab, scratch,
+                interpret):
+    """pallas_call over grid (Bp/bb, bb): one sample per step, the
+    accumulator and output block resident for a whole batch block."""
+    sample = lambda i, j: i * bb + j
+    return pl.pallas_call(
+        kernel,
+        grid=(Bp // bb, bb),
+        in_specs=([index_spec(T, L, sample)] * n_idx
+                  + [pl.BlockSpec((bb, d), lambda i, j: (i, 0))]
+                  + [pl.BlockSpec(memory_space=pl.ANY)] * n_tab),
+        out_specs=pl.BlockSpec((bb, s1, s1), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, s1, s1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bb, s1, d), jnp.float32)] + scratch,
+        interpret=interpret,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Single-tier variant
 # ---------------------------------------------------------------------------
-def _fused_bag_kernel(idx_ref, bot_ref, row_ref, out_ref, acc_ref,
-                      *, bb, T, L):
-    _fused_kernel_body(bot_ref, row_ref[...].astype(jnp.float32), acc_ref,
-                       out_ref, bb=bb, T=T, L=L)
+def _fused_bag_kernel(idx_ref, bot_ref, tab_ref, out_ref, acc_ref, buf, sem,
+                      *, bb, T, L, d, p):
+    _fused_kernel_body(pl.program_id(1), bot_ref, out_ref, acc_ref,
+                       [Part(tab_ref, idx_ref, 0, T, p)], [buf], sem,
+                       bb=bb, T=T, L=L, d=d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -137,7 +152,7 @@ def fused_bag_interactions_pallas(tables: jax.Array, indices: jax.Array,
     """tables (T, R, d), indices (B, T, L) int32, bot_out (B, d)
     -> (B, d + (T+1)T/2) fp32 interaction features, one launch.
 
-    ``interpret=True`` executes the kernel body in Python on CPU (validation
+    ``interpret=True`` executes the kernel body on the host (validation
     mode); on TPU pass ``interpret=False``.
     """
     T, R, d = tables.shape
@@ -145,25 +160,14 @@ def fused_bag_interactions_pallas(tables: jax.Array, indices: jax.Array,
     assert T == T2 and bot_out.shape == (B, d), \
         (tables.shape, indices.shape, bot_out.shape)
     s1 = T + 1
+    lines, p = lines_for_kernel("fused_bag_interactions_pallas", tables,
+                                interpret)
     bot_p, (idx_p,), bb, Bp = _pad_batch(bot_out, [indices], block_b)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Bp // bb, bb, T, L),
-        in_specs=[
-            pl.BlockSpec((bb, d), lambda i, j, t, l, idx: (i, 0)),
-            pl.BlockSpec((1, 1, d),
-                         lambda i, j, t, l, idx: (t, idx[i * bb + j, t, l], 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, s1, s1), lambda i, j, t, l, idx: (i, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bb, s1, d), jnp.float32)],
-    )
-    f = pl.pallas_call(
-        functools.partial(_fused_bag_kernel, bb=bb, T=T, L=L),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, s1, s1), jnp.float32),
-        interpret=interpret,
-    )(idx_p, bot_p, tables)
+    f = _fused_call(
+        functools.partial(_fused_bag_kernel, bb=bb, T=T, L=L, d=d, p=p),
+        bb=bb, Bp=Bp, T=T, L=L, d=d, s1=s1, n_idx=1, n_tab=1,
+        scratch=line_scratch([(p * d, tables.dtype)], L),
+        interpret=interpret)(flat_indices(idx_p), bot_p, lines)
     return _finalize(bot_out, f)
 
 
@@ -171,10 +175,12 @@ def fused_bag_interactions_pallas(tables: jax.Array, indices: jax.Array,
 # Two-tier (cached fast/bulk) variant
 # ---------------------------------------------------------------------------
 def _fused_cached_kernel(fi_ref, bi_ref, bot_ref, fast_ref, bulk_ref,
-                         out_ref, acc_ref, *, bb, T, L):
-    row = (fast_ref[...].astype(jnp.float32)
-           + bulk_ref[...].astype(jnp.float32))
-    _fused_kernel_body(bot_ref, row, acc_ref, out_ref, bb=bb, T=T, L=L)
+                         out_ref, acc_ref, fbuf, bbuf, sem,
+                         *, bb, T, L, d, pf, pb):
+    _fused_kernel_body(pl.program_id(1), bot_ref, out_ref, acc_ref,
+                       [Part(fast_ref, fi_ref, 0, T, pf),
+                        Part(bulk_ref, bi_ref, 0, T, pb)],
+                       [fbuf, bbuf], sem, bb=bb, T=T, L=L, d=d)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -185,43 +191,32 @@ def fused_cached_bag_interactions_pallas(
     """Two-tier layout (``cached_embedding_bag.py``): fast (T, S+1, d) with
     zeros miss slot S, bulk (T, R+1, d) with zeros hit slot R, pre-translated
     fast_idx/bulk_idx (B, T, L); bot_out (B, d) -> fused features, one
-    launch. Each step DMAs one row from each tier (exactly one is the zero
-    pad), so padded batch rows are harmless by the same argument: slot S /
-    slot R are zeros and the padded interaction rows are discarded."""
+    launch. Each lookup DMAs one line from each tier (exactly one row is
+    the zero pad), so padded batch rows are harmless by the same argument:
+    slot S / slot R are zeros and the padded interaction rows are
+    discarded."""
     T, S1, d = fast.shape
     T2, R1, d2 = bulk.shape
     B, T3, L = fast_idx.shape
     assert T == T2 == T3 and d == d2 and fast_idx.shape == bulk_idx.shape
     assert bot_out.shape == (B, d), (bot_out.shape, (B, d))
     s1 = T + 1
+    name = "fused_cached_bag_interactions_pallas"
+    fast_l, pf = lines_for_kernel(name, fast, interpret)
+    bulk_l, pb = lines_for_kernel(name, bulk, interpret)
     # pad index value S / R is NOT zero-filled by _pad_batch's jnp.pad(0) —
     # row 0 of either tier is a real row; pad SAMPLES still only write
     # accumulator rows whose output is sliced off, so 0 is fine.
     bot_p, (fi_p, bi_p), bb, Bp = _pad_batch(bot_out, [fast_idx, bulk_idx],
                                              block_b)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Bp // bb, bb, T, L),
-        in_specs=[
-            pl.BlockSpec((bb, d), lambda i, j, t, l, fi, bi: (i, 0)),
-            pl.BlockSpec((1, 1, d),
-                         lambda i, j, t, l, fi, bi:
-                         (t, fi[i * bb + j, t, l], 0)),
-            pl.BlockSpec((1, 1, d),
-                         lambda i, j, t, l, fi, bi:
-                         (t, bi[i * bb + j, t, l], 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, s1, s1),
-                               lambda i, j, t, l, fi, bi: (i, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bb, s1, d), jnp.float32)],
-    )
-    f = pl.pallas_call(
-        functools.partial(_fused_cached_kernel, bb=bb, T=T, L=L),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, s1, s1), jnp.float32),
-        interpret=interpret,
-    )(fi_p, bi_p, bot_p, fast, bulk)
+    f = _fused_call(
+        functools.partial(_fused_cached_kernel, bb=bb, T=T, L=L, d=d,
+                          pf=pf, pb=pb),
+        bb=bb, Bp=Bp, T=T, L=L, d=d, s1=s1, n_idx=2, n_tab=2,
+        scratch=line_scratch([(pf * d, fast.dtype), (pb * d, bulk.dtype)],
+                             L),
+        interpret=interpret)(flat_indices(fi_p), flat_indices(bi_p), bot_p,
+                             fast_l, bulk_l)
     return _finalize(bot_out, f)
 
 
@@ -229,14 +224,14 @@ def fused_cached_bag_interactions_pallas(
 # Grouped (tiered-plan fast/bulk table split) variant
 # ---------------------------------------------------------------------------
 def _fused_grouped_kernel(idx_ref, bot_ref, fast_ref, bulk_ref, out_ref,
-                          acc_ref, *, bb, T, L, n_fast):
-    t = pl.program_id(2)
-    # both groups DMA a row every step (the cached-bag discipline: index
-    # maps are clamped to stay in range); only the owning group's row lands
-    row = jnp.where(t < n_fast,
-                    fast_ref[...].astype(jnp.float32),
-                    bulk_ref[...].astype(jnp.float32))
-    _fused_kernel_body(bot_ref, row, acc_ref, out_ref, bb=bb, T=T, L=L)
+                          acc_ref, fbuf, bbuf, sem, *, bb, T, L, d, n_fast,
+                          pf, pb):
+    # tables [0, n_fast) come from the fast group, the rest from the bulk
+    # group; each lookup DMAs from its own group only
+    _fused_kernel_body(pl.program_id(1), bot_ref, out_ref, acc_ref,
+                       [Part(fast_ref, idx_ref, 0, n_fast, pf),
+                        Part(bulk_ref, idx_ref, n_fast, T, pb)],
+                       [fbuf, bbuf], sem, bb=bb, T=T, L=L, d=d)
 
 
 @functools.partial(jax.jit,
@@ -252,7 +247,7 @@ def fused_grouped_bag_interactions_pallas(
     ``PlanGroups.inv_perm``) restores original order in the output gather.
 
     An empty group delegates to the single-tier kernel (a (0, R, d) operand
-    has no rows to block-spec over)."""
+    has no rows to gather from)."""
     Tf = tables_fast.shape[0]
     Tb = tables_bulk.shape[0]
     T = Tf + Tb
@@ -275,31 +270,15 @@ def fused_grouped_bag_interactions_pallas(
     d = tables_fast.shape[2]
     assert tables_bulk.shape[2] == d and bot_out.shape == (B, d)
     s1 = T + 1
+    name = "fused_grouped_bag_interactions_pallas"
+    fast_l, pf = lines_for_kernel(name, tables_fast, interpret)
+    bulk_l, pb = lines_for_kernel(name, tables_bulk, interpret)
     bot_p, (idx_p,), bb, Bp = _pad_batch(bot_out, [indices_perm], block_b)
-
-    def fast_map(i, j, t, l, idx):
-        r = jnp.where(t < Tf, idx[i * bb + j, t, l], 0)
-        return (jnp.minimum(t, Tf - 1), r, 0)
-
-    def bulk_map(i, j, t, l, idx):
-        r = jnp.where(t >= Tf, idx[i * bb + j, t, l], 0)
-        return (jnp.clip(t - Tf, 0, Tb - 1), r, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Bp // bb, bb, T, L),
-        in_specs=[
-            pl.BlockSpec((bb, d), lambda i, j, t, l, idx: (i, 0)),
-            pl.BlockSpec((1, 1, d), fast_map),
-            pl.BlockSpec((1, 1, d), bulk_map),
-        ],
-        out_specs=pl.BlockSpec((bb, s1, s1), lambda i, j, t, l, idx: (i, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bb, s1, d), jnp.float32)],
-    )
-    f = pl.pallas_call(
-        functools.partial(_fused_grouped_kernel, bb=bb, T=T, L=L, n_fast=Tf),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, s1, s1), jnp.float32),
-        interpret=interpret,
-    )(idx_p, bot_p, tables_fast, tables_bulk)
+    f = _fused_call(
+        functools.partial(_fused_grouped_kernel, bb=bb, T=T, L=L, d=d,
+                          n_fast=Tf, pf=pf, pb=pb),
+        bb=bb, Bp=Bp, T=T, L=L, d=d, s1=s1, n_idx=1, n_tab=2,
+        scratch=line_scratch([(pf * d, tables_fast.dtype),
+                              (pb * d, tables_bulk.dtype)], L),
+        interpret=interpret)(flat_indices(idx_p), bot_p, fast_l, bulk_l)
     return _finalize(bot_out, f, inv_perm=inv_perm)

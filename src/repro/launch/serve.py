@@ -452,4 +452,6 @@ def _cluster_main(args, cfg, full_cfg) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

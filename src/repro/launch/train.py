@@ -35,9 +35,12 @@ def _run_with_deltas(args, session):
         raise SystemExit(
             "--emit-deltas needs stacked params with a 'tables' leaf "
             "(dlrm workload, --plan none, no host tier)")
+    from repro.core.table_layout import to_rows
+
+    d = session.cfg.embed_dim
     channel = DeltaChannel()
     seg = max(1, args.delta_every_steps)
-    snap = np.array(params["tables"])
+    snap = to_rows(np.array(params["tables"]), d)
     reports = []
     done = 0
     version = 0
@@ -46,7 +49,7 @@ def _run_with_deltas(args, session):
         reports.append(session.run(n))
         done += n
         version += 1
-        new = np.array(session.params["tables"])
+        new = to_rows(np.array(session.params["tables"]), d)
         channel.push(diff_tables(
             snap, new, version=version, t_emit_s=version * args.delta_dt_s,
             step=done, train_loss=reports[-1].last_loss))
@@ -181,4 +184,6 @@ def main(argv: Optional[list] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -22,7 +22,7 @@ PARAM_DTYPE = jnp.float32
 class Sharder:
     """Applies with_sharding_constraint when a mesh is attached; no-op otherwise.
 
-    Axis-name conventions (see DESIGN.md):
+    Axis-name conventions:
       batch    -> ("data",)            (plus "pod" when multi-pod data-parallel)
       model/TP -> ("model",)
     A constraint is only applied if the dim is divisible by the mesh axis size,

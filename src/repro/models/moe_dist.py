@@ -1,6 +1,6 @@
 """Distributed MoE dispatch (§Perf hillclimb for the MoE cells).
 
-BASELINE pathology (recorded in EXPERIMENTS.md §Perf): `moe_block`'s
+BASELINE pathology: `moe_block`'s
 token→expert scatter is written on GLOBAL shapes; the scatter indices are
 data-dependent, so GSPMD cannot prove locality and falls back to gathering
 the full token buffer onto every chip — mixtral train_4k showed 365 GiB/dev
@@ -39,17 +39,10 @@ def _capacity(n_tokens: int, k: int, e: int, factor: float) -> int:
 
 
 def _shard_map_manual(body, mesh, in_specs, out_specs, manual_axes):
-    """Manual-over-`manual_axes`, auto-over-the-rest shard_map, across jax
-    versions: jax>=0.5 exposes `jax.shard_map(axis_names=...)`; 0.4.x only
-    has the experimental API where the complement set is passed as `auto`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    auto = frozenset(mesh.axis_names) - set(manual_axes)
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False, auto=auto)
+    """shard_map that is manual over `manual_axes` and auto over the rest."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual_axes),
+                         check_vma=False)
 
 
 def _pack_by_segment(seg_ids: jax.Array, n_segments: int, capacity: int

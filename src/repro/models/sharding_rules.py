@@ -1,6 +1,6 @@
 """PartitionSpec rules for LM parameters, optimizer state, and KV caches.
 
-Strategy (DESIGN.md §2): weights are 2D-sharded — `data` acts as the
+Strategy: weights are 2D-sharded — `data` acts as the
 FSDP/ZeRO-3 axis, `model` as the tensor-parallel axis. The `pod` axis is
 pure data parallelism (params replicated across pods; only gradient
 all-reduce crosses it) — the paper's scale-in principle: latency-bound
@@ -12,7 +12,7 @@ transformer.init_model), so they survive arbitrary nesting/stacking.
 Divisibility policy: a spec axis is applied only if the dim divides the
 mesh axis size — otherwise that dim falls back to replicated (e.g. GQA
 kv_heads=8 < model=16 ⇒ wk/wv are FSDP-sharded but NOT tensor-sharded,
-matching "KV heads replicated" in DESIGN.md).
+i.e. KV heads replicated).
 """
 from __future__ import annotations
 

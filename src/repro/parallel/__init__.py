@@ -14,8 +14,9 @@ Decomposes the former `core/sharding.py` monolith into:
 `core.sharding` re-exports this namespace for backward compatibility.
 """
 from repro.parallel.build import (build_step, init_dlrm_opt_state,
-                                  init_error_feedback, param_specs,
-                                  shard_dlrm_params)
+                                  init_dlrm_params, init_error_feedback,
+                                  param_specs, shard_dlrm_params,
+                                  table_rows_per_line)
 from repro.parallel.exchange import (EmbeddingExchange, PlannedTieredExchange,
                                      RowWiseExchange, TableWiseExchange,
                                      acc_key, make_exchange, planned_forward)
@@ -33,7 +34,8 @@ from repro.parallel.updates import adagrad_row_update, sgd_row_update
 __all__ = [
     "EmbeddingExchange", "TableWiseExchange", "RowWiseExchange",
     "PlannedTieredExchange", "make_exchange", "acc_key", "planned_forward",
-    "build_step", "param_specs", "shard_dlrm_params", "init_dlrm_opt_state",
+    "build_step", "param_specs", "shard_dlrm_params", "init_dlrm_params",
+    "table_rows_per_line", "init_dlrm_opt_state",
     "init_error_feedback",
     "PlanGroups", "plan_table_groups", "reconcile_plan_with_mesh",
     "split_dlrm_params_by_plan", "merge_dlrm_params_by_plan",

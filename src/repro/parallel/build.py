@@ -36,11 +36,11 @@ from typing import Any, Callable, Dict, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import DLRMConfig
 from repro.core import dlrm as dlrm_lib
 from repro.core.planner import ShardingPlan
+from repro.core.table_layout import rows_per_line, to_lines
 from repro.optim.compression import make_compressed_allreduce
 from repro.parallel.exchange import (EmbeddingExchange, acc_key,
                                      make_exchange)
@@ -78,20 +78,61 @@ def param_specs(cfg: DLRMConfig, axis: Axis,
     return {"bot_mlp": mlp_spec, "top_mlp": top_spec, "tables": tables}
 
 
+def table_rows_per_line(cfg: DLRMConfig, mesh: Mesh, axis: Axis) -> int:
+    """Storage layout of placed tables (`core/table_layout.py`): lane-dense
+    lines of ``p`` rows on a TPU mesh, rows (``p = 1``) elsewhere. A
+    row-sharded table keeps whole lines on each device."""
+    if mesh.devices.flat[0].platform != "tpu":
+        return 1
+    return rows_per_line(cfg.embed_dim,
+                         cfg.rows_per_table // axis_size(mesh, axis))
+
+
+def _placement(cfg: DLRMConfig, mesh: Mesh, axis: Axis,
+               plan: Optional[ShardingPlan]):
+    groups = None
+    if plan is not None and plan.placements:
+        groups = plan_table_groups(plan, axis_size(mesh, axis))
+    shardings = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), param_specs(cfg, axis, groups),
+        is_leaf=lambda x: isinstance(x, P))
+    return groups, shardings, table_rows_per_line(cfg, mesh, axis)
+
+
+def _layout_tables(params: Params, groups: Optional[PlanGroups],
+                   p: int, d: int) -> Params:
+    """Split stacked tables into the plan's groups and store every table
+    key in the placement's layout (tables already in lines pass through)."""
+    if groups is not None and "tables" in params:
+        params = split_dlrm_params_by_plan(params, groups)
+    return {k: (to_lines(v, p) if k.startswith("tables") and p > 1
+                and v.shape[-1] == d else v)
+            for k, v in params.items()}
+
+
 def shard_dlrm_params(params: Params, cfg: DLRMConfig, mesh: Mesh,
                       axis: Axis, plan: Optional[ShardingPlan] = None
                       ) -> Params:
     """Device-place DLRM params. With a placed `plan`, stacked params are
-    first split into the plan's fast/bulk table groups."""
-    groups = None
-    if plan is not None and plan.placements:
-        groups = plan_table_groups(plan, axis_size(mesh, axis))
-        if "tables" in params:
-            params = split_dlrm_params_by_plan(params, groups)
-    specs = param_specs(cfg, axis, groups)
-    return jax.tree_util.tree_map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-        params, specs, is_leaf=lambda x: isinstance(x, P))
+    first split into the plan's fast/bulk table groups; tables are stored
+    in the mesh's layout (`table_rows_per_line`)."""
+    groups, shardings, p = _placement(cfg, mesh, axis, plan)
+    params = _layout_tables(params, groups, p, cfg.embed_dim)
+    return jax.tree_util.tree_map(jax.device_put, params, shardings)
+
+
+def init_dlrm_params(key: jax.Array, cfg: DLRMConfig, mesh: Mesh,
+                     axis: Axis, plan: Optional[ShardingPlan] = None
+                     ) -> Params:
+    """`dlrm_lib.init_dlrm`, built placed: one jitted program writes every
+    param straight into its sharding and layout, so no device ever holds
+    more than its own share of the tables. Same values as
+    ``shard_dlrm_params(init_dlrm(key, cfg), ...)``."""
+    groups, shardings, p = _placement(cfg, mesh, axis, plan)
+    return jax.jit(
+        lambda k: _layout_tables(dlrm_lib.init_dlrm(k, cfg), groups, p,
+                                 cfg.embed_dim),
+        out_shardings=shardings)(key)
 
 
 def _dense_param_abstract(cfg: DLRMConfig) -> Dict[str, Any]:
@@ -291,9 +332,9 @@ def build_step(
                 return (outs[0] if depth == 1
                         else jnp.concatenate(outs, axis=0))
 
-        smapped = shard_map(serve, mesh=mesh,
-                            in_specs=(p_specs, data_spec, data_spec),
-                            out_specs=data_spec, check_rep=False)
+        smapped = jax.shard_map(serve, mesh=mesh,
+                                in_specs=(p_specs, data_spec, data_spec),
+                                out_specs=data_spec, check_vma=False)
         return jax.jit(smapped)
 
     # ---------------- train: fwd/bwd pipeline + grad stages ----------------
@@ -392,10 +433,10 @@ def build_step(
         new_params = {**new_dense, **new_tables}
         return new_params, (new_opt or None), loss
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(p_specs, opt_specs, data_spec, data_spec, data_spec),
         out_specs=(p_specs, opt_specs, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(smapped, donate_argnums=(0, 1))

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import DLRMConfig
 from repro.core.planner import ShardingPlan
+from repro.core.table_layout import to_rows
 from repro.parallel import primitives as prim
 from repro.parallel.plan import PlanGroups, plan_table_groups
 
@@ -149,7 +150,8 @@ class TableWiseExchange(EmbeddingExchange):
         return {"table_acc": P(self.axis)}
 
     def forward(self, tables, indices):
-        return prim.table_wise_forward(tables["tables"], indices, self.axis)
+        return prim.table_wise_forward(tables["tables"], indices, self.axis,
+                                       self.cfg.embed_dim)
 
     def expand_grads(self, tables, ctx, g_pooled):
         return {"tables": prim.table_wise_expand_grads(ctx, g_pooled,
@@ -165,8 +167,9 @@ class TableWiseExchange(EmbeddingExchange):
 
     def fused_forward(self, tables, bot_out, indices):
         from repro import kernels
-        return kernels.fused_bag_interactions(tables["tables"], indices,
-                                              bot_out)
+        d = self.cfg.embed_dim
+        return kernels.fused_bag_interactions(to_rows(tables["tables"], d),
+                                              indices, bot_out)
 
 
 class RowWiseExchange(EmbeddingExchange):
@@ -191,7 +194,8 @@ class RowWiseExchange(EmbeddingExchange):
 
     def forward(self, tables, indices):
         return prim.row_wise_forward(tables["tables"], indices, self.axis,
-                                     self.n, self.mode, self.lookup_chunk)
+                                     self.n, self.mode, self.lookup_chunk,
+                                     d=self.cfg.embed_dim)
 
     def expand_grads(self, tables, ctx, g_pooled):
         return {"tables": prim.row_wise_expand_grads(
@@ -205,7 +209,7 @@ class RowWiseExchange(EmbeddingExchange):
 
 def planned_forward(tables_fast, tables_bulk, indices_local, axis: Axis,
                     mesh_n: int, exchange: str, groups: PlanGroups,
-                    lookup_chunk: int = 4096,
+                    lookup_chunk: int = 4096, d: Optional[int] = None,
                     ) -> Tuple[Any, Optional[Any], Optional[Any]]:
     """Mixed-mode Alg. 1 executing the planner's placements: fast-tier
     tables table_wise, bulk-tier tables row_wise, pooled outputs re-stitched
@@ -220,13 +224,14 @@ def planned_forward(tables_fast, tables_bulk, indices_local, axis: Axis,
     ctx_fast = ctx_bulk = None
     if groups.fast_ids:
         idx_f = indices_local[:, np.asarray(groups.fast_ids, np.int32), :]
-        pooled_f, ctx_fast = prim.table_wise_forward(tables_fast, idx_f, axis)
+        pooled_f, ctx_fast = prim.table_wise_forward(tables_fast, idx_f, axis,
+                                                     d)
         parts.append(pooled_f)
     if groups.bulk_ids:
         idx_b = indices_local[:, np.asarray(groups.bulk_ids, np.int32), :]
         pooled_b, ctx_bulk = prim.row_wise_forward(tables_bulk, idx_b, axis,
                                                    mesh_n, exchange,
-                                                   lookup_chunk)
+                                                   lookup_chunk, d=d)
         parts.append(pooled_b)
     pooled = jnp.concatenate(parts, axis=1)
     pooled = pooled[:, np.asarray(groups.inv_perm, np.int32), :]
@@ -268,7 +273,7 @@ class PlannedTieredExchange(EmbeddingExchange):
         pooled, ctx_f, ctx_b = planned_forward(
             tables["tables_fast"], tables["tables_bulk"], indices,
             self.axis, self.n, self.row_mode, self.groups,
-            self.lookup_chunk)
+            self.lookup_chunk, d=self.cfg.embed_dim)
         return pooled, (ctx_f, ctx_b)
 
     def supports_fused_forward(self) -> bool:
@@ -278,9 +283,11 @@ class PlannedTieredExchange(EmbeddingExchange):
 
     def fused_forward(self, tables, bot_out, indices):
         from repro import kernels
+        d = self.cfg.embed_dim
         idx_perm = indices[:, self._perm_arr, :]
         return kernels.fused_grouped_bag_interactions(
-            tables["tables_fast"], tables["tables_bulk"], idx_perm, bot_out,
+            to_rows(tables["tables_fast"], d),
+            to_rows(tables["tables_bulk"], d), idx_perm, bot_out,
             inv_perm=self.groups.inv_perm)
 
     def _split_g(self, g_pooled):

@@ -31,10 +31,14 @@ owners (all-to-all for table_wise; all-gather for row_wise — exactly the
 paper's two cases), expanded to every looked-up row (`expand_sparse_grads`)
 and scatter-added. The dense (T,R,d) embedding gradient is NEVER
 materialized.
+
+Local tables may be stored as rows or as lane-dense lines
+(`core/table_layout.py`); the forward functions take the row width ``d``
+(default: the stored width, i.e. rows).
 """
 from __future__ import annotations
 
-from typing import Callable, Tuple, Union
+from typing import Callable, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +46,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.core import dlrm as dlrm_lib
+from repro.core.table_layout import gather_rows, num_rows
 
 Axis = Union[str, Tuple[str, ...]]
 
@@ -59,7 +64,8 @@ def axis_size(mesh: Mesh, axis: Axis) -> int:
 # Table-wise (paper "unsharded") exchange
 # ---------------------------------------------------------------------------
 def table_wise_forward(tables_local: jax.Array, indices_local: jax.Array,
-                       axis: Axis) -> Tuple[jax.Array, jax.Array]:
+                       axis: Axis, d: Optional[int] = None
+                       ) -> Tuple[jax.Array, jax.Array]:
     """Alg. 1, no_sharding branch.
 
     tables_local : (T/n, R, d) — this processor's whole tables
@@ -70,7 +76,7 @@ def table_wise_forward(tables_local: jax.Array, indices_local: jax.Array,
     # indices all-to-all: batch-major -> table-major
     owner_idx = jax.lax.all_to_all(indices_local, axis, split_axis=1,
                                    concat_axis=0, tiled=True)   # (B, T/n, L)
-    pooled_owner = dlrm_lib.embedding_bag(tables_local, owner_idx)  # (B, T/n, d)
+    pooled_owner = dlrm_lib.embedding_bag(tables_local, owner_idx, d)  # (B, T/n, d)
     # pooled-embedding all-to-all: table-major -> batch-major
     pooled = jax.lax.all_to_all(pooled_owner, axis, split_axis=0,
                                 concat_axis=1, tiled=True)      # (B/n, T, d)
@@ -116,32 +122,34 @@ def _divisor_chunk(n: int, target: int) -> int:
 
 
 def _masked_rows(tables_local: jax.Array, idx: jax.Array,
-                 r_start: jax.Array) -> jax.Array:
+                 r_start: jax.Array, d: Optional[int] = None) -> jax.Array:
     """Gather locally-owned rows (zeros elsewhere). idx (B', T, L) global ids
     -> (B', T, L, d)."""
-    rows_local = tables_local.shape[1]
+    d = d or tables_local.shape[-1]
+    rows_local = num_rows(tables_local, d)
     local = idx - r_start
     mine = (local >= 0) & (local < rows_local)
     safe = jnp.where(mine, local, 0)
 
-    def gather_table(tab, i, m):           # (R/n,d), (B',L), (B',L)
-        rows = jnp.take(tab, i, axis=0)                      # (B', L, d)
+    def gather_table(tab, i, m):           # (R/n rows), (B',L), (B',L)
+        rows = gather_rows(tab, i, d)                        # (B', L, d)
         return rows * m[..., None].astype(rows.dtype)
     return jax.vmap(gather_table, in_axes=(0, 1, 1), out_axes=1)(
         tables_local, safe, mine)                            # (B', T, L, d)
 
 
 def _masked_partial_pool(tables_local: jax.Array, idx: jax.Array,
-                         r_start: jax.Array) -> jax.Array:
+                         r_start: jax.Array, d: Optional[int] = None
+                         ) -> jax.Array:
     """Partial sum-pool of locally-owned rows. idx (B', T, L) global ids ->
     (B', T, d) partial pools (zeros for rows owned elsewhere)."""
-    return _masked_rows(tables_local, idx, r_start).sum(axis=2)
+    return _masked_rows(tables_local, idx, r_start, d).sum(axis=2)
 
 
 def row_wise_forward(tables_local: jax.Array, indices_local: jax.Array,
                      axis: Axis, mesh_n: int,
                      exchange: str = "partial_pool",
-                     lookup_chunk: int = 4096,
+                     lookup_chunk: int = 4096, d: Optional[int] = None,
                      ) -> Tuple[jax.Array, jax.Array]:
     """Alg. 1, full_sharding branch.
 
@@ -154,14 +162,14 @@ def row_wise_forward(tables_local: jax.Array, indices_local: jax.Array,
     row block is the only L-sized tensor ever live (the partial pools
     accumulate per chunk), keeping VMEM/HBM pressure flat in B.
     """
-    rows_local = tables_local.shape[1]
+    d = d or tables_local.shape[-1]
+    rows_local = num_rows(tables_local, d)
     rank = jax.lax.axis_index(axis)
     r_start = rank * rows_local
 
     # Index exchange: every owner needs the full batch's indices.
     idx_all = jax.lax.all_gather(indices_local, axis, axis=0, tiled=True)  # (B,T,L)
     B, T, L = idx_all.shape
-    d = tables_local.shape[-1]
 
     if exchange == "unpooled":
         # Paper-faithful: ship UNPOOLED rows; pool at the home processor.
@@ -171,7 +179,7 @@ def row_wise_forward(tables_local: jax.Array, indices_local: jax.Array,
         Bn = B // mesh_n
         Cp = _divisor_chunk(Bn, max(1, lookup_chunk // mesh_n))
         if Bn == Cp:
-            rows = _masked_rows(tables_local, idx_all, r_start)   # (B,T,L,d)
+            rows = _masked_rows(tables_local, idx_all, r_start, d)  # (B,T,L,d)
             unpooled = jax.lax.psum_scatter(rows, axis, scatter_dimension=0,
                                             tiled=True)           # (B/n,T,L,d)
             return unpooled.sum(axis=2), idx_all
@@ -180,7 +188,7 @@ def row_wise_forward(tables_local: jax.Array, indices_local: jax.Array,
         def chunk_body(_, k):
             idx_c = jax.lax.dynamic_slice_in_dim(
                 idx_r, k * Cp, Cp, axis=1).reshape(mesh_n * Cp, T, L)
-            rows = _masked_rows(tables_local, idx_c, r_start)     # (nC',T,L,d)
+            rows = _masked_rows(tables_local, idx_c, r_start, d)  # (nC',T,L,d)
             unpooled_c = jax.lax.psum_scatter(
                 rows, axis, scatter_dimension=0, tiled=True)      # (C',T,L,d)
             return None, unpooled_c.sum(axis=2)                   # pool over L
@@ -191,12 +199,12 @@ def row_wise_forward(tables_local: jax.Array, indices_local: jax.Array,
 
     # partial_pool (beyond-paper): pool owned rows locally, reduce-scatter.
     if B <= lookup_chunk:
-        partial = _masked_partial_pool(tables_local, idx_all, r_start)
+        partial = _masked_partial_pool(tables_local, idx_all, r_start, d)
     else:
         chunk = _divisor_chunk(B, lookup_chunk)
         chunks = idx_all.reshape(B // chunk, chunk, T, L)
         partial = jax.lax.map(
-            lambda ic: _masked_partial_pool(tables_local, ic, r_start),
+            lambda ic: _masked_partial_pool(tables_local, ic, r_start, d),
             chunks).reshape(B, T, d)
 
     pooled = jax.lax.psum_scatter(partial, axis, scatter_dimension=0,
@@ -209,7 +217,7 @@ def row_wise_expand_grads(tables_local: jax.Array, ctx: jax.Array,
                           ) -> Tuple[jax.Array, jax.Array]:
     """Alg. 2 full_sharding grad routing: all-gather pooled grads, mask to
     locally-owned rows. Returns (flat_idx (T, N), flat_g (T, N, d))."""
-    rows_local = tables_local.shape[1]
+    rows_local = num_rows(tables_local, g_pooled.shape[-1])
     rank = jax.lax.axis_index(axis)
     r_start = rank * rows_local
     g_all = jax.lax.all_gather(g_pooled, axis, axis=0, tiled=True)
@@ -233,7 +241,7 @@ def row_wise_backward_update(
     """Alg. 2, full_sharding branch: all-gather pooled grads, expand to the
     locally-owned rows, scatter-add. Chunked over the batch like the forward
     (the expanded (chunk, T, L, d) grad block is the only L-sized tensor)."""
-    rows_local = tables_local.shape[1]
+    rows_local = num_rows(tables_local, g_pooled_local.shape[-1])
     rank = jax.lax.axis_index(axis)
     r_start = rank * rows_local
 

@@ -121,7 +121,6 @@ from repro.core import dlrm as dlrm_lib
 from repro.core import sharding as dsh
 from repro.data import make_recsys_batch
 from repro.launch.mesh import make_mesh
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 # chunked row-wise lookup == unchunked (associativity of partial pooling)
@@ -136,9 +135,9 @@ def fwd(chunk):
         pooled, _ = dsh.row_wise_forward(tables, idx, "x", 8,
                                          "partial_pool", lookup_chunk=chunk)
         return pooled
-    return jax.jit(shard_map(f, mesh=mesh,
-                             in_specs=(P(None, "x"), P("x")),
-                             out_specs=P("x"), check_rep=False))
+    return jax.jit(jax.shard_map(f, mesh=mesh,
+                                 in_specs=(P(None, "x"), P("x")),
+                                 out_specs=P("x"), check_vma=False))
 
 p1 = jax.device_get(fwd(8)(params["tables"], b["indices"]))
 p2 = jax.device_get(fwd(10**9)(params["tables"], b["indices"]))

@@ -6,9 +6,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.cached_embedding_bag import cached_embedding_bag_pallas
-from repro.kernels.embedding_bag import (blocked_stream_aligned,
-                                         embedding_bag_pallas,
-                                         embedding_bag_pallas_blocked)
+from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.interactions import interactions_pallas
@@ -40,43 +38,6 @@ def test_embedding_bag_repeated_indices():
     np.testing.assert_allclose(out[0, 0], 3 * tables[0, 1])
 
 
-# ------------------------------------------------- blocked embedding variant
-def _aligned_stream(key, B, T, L, R, lblk):
-    """Each L-block covers consecutive rows [k*lblk, (k+1)*lblk)."""
-    base = jax.random.randint(key, (B, T, L // lblk, 1), 0, R // lblk) * lblk
-    return (base + jnp.arange(lblk)).reshape(B, T, L).astype(jnp.int32)
-
-
-def test_embedding_bag_blocked_aligned_stream():
-    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
-    tables = jax.random.normal(k1, (3, 64, 16))
-    idx = _aligned_stream(k2, 4, 3, 8, 64, 4)
-    assert bool(blocked_stream_aligned(idx, 4))
-    out = embedding_bag_pallas_blocked(tables, idx, lblk=4)
-    np.testing.assert_allclose(out, ref.embedding_bag_ref(tables, idx),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_embedding_bag_blocked_misaligned_falls_back():
-    """Regression: the blocked kernel used to silently pool WRONG rows on
-    non-lblk-aligned / non-consecutive streams; it must now detect the
-    misalignment and fall back to the per-row kernel."""
-    k1, k2 = jax.random.split(jax.random.PRNGKey(4))
-    tables = jax.random.normal(k1, (2, 64, 8))
-    # arbitrary (unsorted) stream — essentially never block-aligned
-    idx = jax.random.randint(k2, (4, 2, 8), 0, 64)
-    assert not bool(blocked_stream_aligned(idx, 4))
-    out = embedding_bag_pallas_blocked(tables, idx, lblk=4)
-    np.testing.assert_allclose(out, ref.embedding_bag_ref(tables, idx),
-                               rtol=1e-5, atol=1e-5)
-    # aligned base but shuffled within the block: also misaligned
-    idx2 = _aligned_stream(k2, 2, 2, 8, 64, 4)[..., ::-1]
-    assert not bool(blocked_stream_aligned(idx2, 4))
-    out2 = embedding_bag_pallas_blocked(tables, idx2, lblk=4)
-    np.testing.assert_allclose(out2, ref.embedding_bag_ref(tables, idx2),
-                               rtol=1e-5, atol=1e-5)
-
-
 # ------------------------------------------------------- cached (tiered) bag
 @pytest.mark.parametrize("B,T,L,R,S,d", [
     (4, 3, 8, 64, 16, 32),
@@ -93,6 +54,21 @@ def test_cached_embedding_bag_matches_ref(B, T, L, R, S, d):
                                       bulk_idx.astype(jnp.int32))
     expect = ref.cached_embedding_bag_ref(fast, bulk, fast_idx, bulk_idx)
     np.testing.assert_allclose(out, expect, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("which", ["embedding_bag", "cached_embedding_bag"])
+def test_native_bag_names_a_table_without_lane_dense_lines(which):
+    """Natively a DMA'd line must span the 128 lanes. A d=32 table whose
+    row count is not a multiple of 4 has no such layout: the kernel says so
+    by name before anything is lowered."""
+    tables = jnp.zeros((2, 10, 32))
+    idx = jnp.zeros((1, 2, 3), jnp.int32)
+    with pytest.raises(ValueError, match=f"{which}_pallas"):
+        if which == "embedding_bag":
+            embedding_bag_pallas(tables, idx, interpret=False)
+        else:
+            cached_embedding_bag_pallas(tables, tables, idx, idx,
+                                        interpret=False)
 
 
 # -------------------------------------------------------------- interactions

@@ -85,7 +85,7 @@ def test_moe_active_params_smaller():
 
 
 def test_sub_quadratic_flags():
-    """long_500k applicability matches DESIGN.md §3."""
+    """long_500k applies only to sub-quadratic architectures."""
     expect_subq = {"rwkv6-3b", "jamba-1.5-large-398b", "h2o-danube-3-4b",
                    "mixtral-8x7b"}
     for name, cfg in ARCHS.items():

@@ -120,3 +120,24 @@ def test_cli_entrypoints(cmd):
     r = subprocess.run(cmd, capture_output=True, text=True, timeout=600,
                        env=CLI_ENV)
     assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-2000:])
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The entry points keep JAX's compile cache where
+    JAX_COMPILATION_CACHE_DIR says, and set nothing then; otherwise in one
+    fixed directory inside the checkout."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.use_compile_cache()
+        assert path == str(compile_cache.CHECKOUT / ".jax_compile_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    gitignore = (compile_cache.CHECKOUT / ".gitignore").read_text()
+    assert ".jax_compile_cache/" in gitignore.split()
