@@ -116,10 +116,16 @@ def dlrm_forward(params: Params, dense: jax.Array, indices: jax.Array,
 def dlrm_forward_from_pooled(params: Params, dense: jax.Array,
                              pooled: jax.Array) -> jax.Array:
     """Dense part only, given pooled embeddings — the differentiable piece
-    of the distributed step (embedding grads flow through `pooled`)."""
-    bot = mlp_forward(params["bot_mlp"], dense)
-    z = feature_interactions(bot, pooled)
-    return mlp_forward(params["top_mlp"], z)[:, 0]
+    of the distributed step (embedding grads flow through `pooled`).
+    Each layer runs under its `jax.named_scope` (``dlrm.bottom_mlp``,
+    ``dlrm.interaction``, ``dlrm.top_mlp``), which XLA keeps in its ops'
+    metadata (backward ops too), so a device trace names the layer."""
+    with jax.named_scope("dlrm.bottom_mlp"):
+        bot = mlp_forward(params["bot_mlp"], dense)
+    with jax.named_scope("dlrm.interaction"):
+        z = feature_interactions(bot, pooled)
+    with jax.named_scope("dlrm.top_mlp"):
+        return mlp_forward(params["top_mlp"], z)[:, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.obs.metrics import MetricsRegistry
+
 
 @dataclass
 class QueryFuture:
@@ -45,11 +47,17 @@ class QueryFuture:
 
 @dataclass
 class MicroBatcher:
-    """Flush-on-size-or-deadline queue of `QueryFuture`s."""
+    """Flush-on-size-or-deadline queue of `QueryFuture`s.
+
+    With a `metrics` registry, `drain(now)` observes each drained query's
+    wait, from its arrival stamp to `now`, in ms into the histogram
+    ``serve_batch_wait_ms``: the batcher's own wait, with nothing of the
+    caller's lateness in it."""
 
     capacity: int                 # max queries per micro-batch
     max_wait_s: float             # oldest-query deadline
     queue: List[QueryFuture] = field(default_factory=list)
+    metrics: Optional[MetricsRegistry] = None
 
     def add(self, fut: QueryFuture) -> bool:
         """Enqueue; returns True if the batch is now full (flush time)."""
@@ -68,8 +76,12 @@ class MicroBatcher:
         return bool(self.queue) and (
             len(self.queue) >= self.capacity or now >= self.deadline())
 
-    def drain(self) -> List[QueryFuture]:
+    def drain(self, now: Optional[float] = None) -> List[QueryFuture]:
         out, self.queue = self.queue, []
+        if self.metrics is not None and now is not None and out:
+            wait = self.metrics.histogram("serve_batch_wait_ms")
+            for f in out:
+                wait.observe((now - f.arrival) * 1e3)
         return out
 
 

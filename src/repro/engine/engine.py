@@ -125,7 +125,8 @@ class Engine:
         self.profile_batches = profile_batches
         self.verbose = verbose
         # run-scoped MetricsRegistry for everything this engine builds
-        # (hoststore exchange swap tallies); None = the process-wide
+        # (hoststore exchange swap tallies, serve sessions' phase and
+        # batch-wait histograms); None = the process-wide
         # default_registry(), the launcher default
         self.metrics = metrics
         self.is_dlrm = isinstance(cfg, DLRMConfig)
@@ -327,7 +328,7 @@ class Engine:
             query_size=query_size, params=params, seed=self.seed,
             alpha=self.alpha, warmup=warmup, pipeline_depth=depth,
             depth_resolver=resolver, dp_axes=self.dp_axes,
-            fused=self.fused_serve != "off")
+            fused=self.fused_serve != "off", metrics=self.metrics)
         # record the kernel selection the session resolved on the cached
         # plan report, so plan_report("inference") tells the whole story
         rep = self._reports.get("inference")

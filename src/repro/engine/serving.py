@@ -15,10 +15,19 @@ model (Eq. 1, PPF(D_Q, P) <= C_SLA):
                         event-by-event. Deterministic and sleep-free, so it
                         is usable from tests and CI while still reflecting
                         the throughput/tail-latency frontier.
+
+The real-time path publishes what it does on the host clock: each flush
+is a `repro.obs.host_span` ``serve.flush`` (flush id, queries, padded
+count, reason ``full``/``deadline``/``forced``, query ids) with children
+``serve.assemble`` (concat + pad, the host-to-device copy),
+``serve.dispatch`` (the step call), ``serve.device_wait``
+(`block_until_ready`) and ``serve.copy_out`` (device-to-host, reshape);
+a new batch shape's compile is ``serve.compile``. Each child's duration
+goes to the session's `MetricsRegistry` as ``serve_<phase>_ms``, and the
+batcher's per-query wait as ``serve_batch_wait_ms``.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -36,7 +45,7 @@ from repro.engine.batching import (MicroBatcher, QueryFuture, now_s,
 from repro.obs.attribution import AttributionLog, BlameReport
 from repro.obs.metrics import default_registry
 from repro.obs.serialize import report_asdict, report_to_json
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, host_span
 
 Query = Dict[str, jax.Array]
 
@@ -102,7 +111,9 @@ class ServeSession:
     Built by `Engine.serve_session()`; do not construct the pipeline by
     hand. Queries are fixed-size (`query_size` samples each — the paper's
     "query of size B", Sec. III-B); the micro-batcher packs up to
-    `max_batch_queries` of them into one device execution.
+    `max_batch_queries` of them into one device execution. `metrics` is
+    the `MetricsRegistry` the request path publishes its phase and wait
+    histograms to (default: the process-wide `default_registry()`).
     """
 
     def __init__(self, cfg: DLRMConfig, mesh, axis, *,
@@ -115,8 +126,10 @@ class ServeSession:
                  warmup: bool = False,
                  pipeline_depth: Optional[int] = 1,
                  depth_resolver: Optional[Callable[[int], int]] = None,
-                 dp_axes: Tuple[str, ...] = (), fused: bool = True):
+                 dp_axes: Tuple[str, ...] = (), fused: bool = True,
+                 metrics=None):
         self.cfg = cfg
+        self.metrics = metrics if metrics is not None else default_registry()
         self.mesh = mesh
         self.plan = plan
         self.seed = seed
@@ -204,8 +217,10 @@ class ServeSession:
         else:
             self.params = parallel.shard_dlrm_params(params, cfg, mesh, axis,
                                                      plan=plan)
-        self.batcher = MicroBatcher(self.max_batch_queries, max_wait_ms / 1e3)
+        self.batcher = MicroBatcher(self.max_batch_queries, max_wait_ms / 1e3,
+                                    metrics=self.metrics)
         self._qid = 0
+        self._flush_id = 0
         self._compiled: set = set()
         # The measurement drivers compile their shapes untimed on first use;
         # eager warmup only matters for the real-time submit path, where the
@@ -261,14 +276,29 @@ class ServeSession:
         b = self.query_size * k
         if b in self._compiled:
             return
-        step = self._get_step(self.depth_for_samples(b))
-        dense = jnp.zeros((b, self.cfg.num_dense), jnp.float32)
-        idx = jnp.zeros((b, self.cfg.num_tables, self.cfg.lookups_per_table),
-                        jnp.int32)
-        step(self.params, dense, idx).block_until_ready()
+        with host_span("serve.compile", self.metrics, samples=b):
+            step = self._get_step(self.depth_for_samples(b))
+            # zero queries through the flush's own assembly, so its concat
+            # is compiled here too and not on the first real flush
+            q = self.query_size
+            zero = {"dense": np.zeros((q, self.cfg.num_dense), np.float32),
+                    "indices": np.zeros((q, self.cfg.num_tables,
+                                         self.cfg.lookups_per_table),
+                                        np.int32)}
+            dense, idx = self._assemble([zero], k)
+            step(self.params, dense, idx).block_until_ready()
         self._compiled.add(b)
 
     # -- execution ---------------------------------------------------------
+    @staticmethod
+    def _assemble(queries: List[Query], k: int
+                  ) -> Tuple[jax.Array, jax.Array]:
+        """The queries as one device batch of ``k`` queries, padded by
+        repeating query 0 (host arrays are copied to the device here)."""
+        parts = list(queries) + [queries[0]] * (k - len(queries))
+        return (jnp.concatenate([p["dense"] for p in parts], axis=0),
+                jnp.concatenate([p["indices"] for p in parts], axis=0))
+
     def serve_direct(self, dense: jax.Array, indices: jax.Array) -> np.ndarray:
         """Run the compiled serve step on one exact batch (no batching/pad)."""
         step = self._get_step(self.depth_for_samples(dense.shape[0]))
@@ -288,11 +318,9 @@ class ServeSession:
         """
         k = self._padded_count(len(queries))
         self._ensure_compiled(k)
-        parts = [q for q in queries]
-        while len(parts) < k:
-            parts.append(queries[0])
-        dense = jnp.concatenate([p["dense"] for p in parts], axis=0)
-        idx = jnp.concatenate([p["indices"] for p in parts], axis=0)
+        m = self.metrics
+        with host_span("serve.assemble", m):
+            dense, idx = self._assemble(queries, k)
         depth = self.depth_for_samples(k * self.query_size)
         step = self._get_step(depth)
         plan = None
@@ -302,17 +330,19 @@ class ServeSession:
             # i's compute on the virtual clock below)
             self.params, plan = self._exchange_inst.begin_batch(
                 self.params, np.asarray(idx), depth)
-        t0 = time.perf_counter()
-        probs = step(self.params, dense, idx)
-        probs.block_until_ready()
-        service = time.perf_counter() - t0
+        with host_span("serve.dispatch", m) as dispatch:
+            probs = step(self.params, dense, idx)
+        with host_span("serve.device_wait", m) as wait:
+            probs.block_until_ready()
+        service = wait.t1 - dispatch.t0
         stall = 0.0
         if plan is not None:
             # modeled swap stall composes with the MEASURED compute time —
             # the bench_pipeline measured+modeled discipline
             stall = self._exchange_inst.stall_seconds(plan, service)
             service += stall
-        out = np.asarray(probs).reshape(k, self.query_size)
+        with host_span("serve.copy_out", m):
+            out = np.asarray(probs).reshape(k, self.query_size)
         return out[:len(queries)], service, stall
 
     # -- request path ------------------------------------------------------
@@ -356,7 +386,7 @@ class ServeSession:
         self._qid += 1
         full = self.batcher.add(fut)
         if full or self.batcher.due(t):
-            self.flush(now=t if now is not None else None)
+            self._flush(now, "full" if full else "deadline")
         return fut
 
     def poll(self, now: Optional[float] = None) -> bool:
@@ -364,16 +394,23 @@ class ServeSession:
         Returns True if a flush happened."""
         t = now_s() if now is None else now
         if self.batcher.due(t):
-            self.flush(now=now)
+            self._flush(now, "deadline")
             return True
         return False
 
     def flush(self, now: Optional[float] = None) -> List[QueryFuture]:
         """Force the queued micro-batch through the device."""
-        futs = self.batcher.drain()
+        return self._flush(now, "forced")
+
+    def _flush(self, now: Optional[float], reason: str) -> List[QueryFuture]:
+        futs = self.batcher.drain(now_s() if now is None else now)
         if not futs:
             return []
-        probs, _, _ = self._execute([f.query for f in futs])
+        fid, self._flush_id = self._flush_id, self._flush_id + 1
+        with host_span("serve.flush", flush=fid, queries=len(futs),
+                       padded=self._padded_count(len(futs)), reason=reason,
+                       qids=" ".join(str(f.qid) for f in futs)):
+            probs, _, _ = self._execute([f.query for f in futs])
         t = now_s() if now is None else now
         for f, p in zip(futs, probs):
             f.complete(p, t)

@@ -6,7 +6,10 @@ fabric, hoststore swap model):
 
   * `obs.trace`       — `Tracer`: nestable spans + instant/counter events
                         per (board, lane) track, exported as Chrome
-                        trace-event JSON loadable in Perfetto.
+                        trace-event JSON loadable in Perfetto; and
+                        `host_span`, the wall-clock span of the real-time
+                        request path (a profiler annotation on the device
+                        trace's clock plus a duration histogram).
   * `obs.metrics`     — `MetricsRegistry`: process-local named counters /
                         gauges / histograms with labels, snapshot-able as
                         a plain dict; the stack's meters publish here.
@@ -24,7 +27,7 @@ from repro.obs.attribution import (COMPONENTS, AttributionLog, BlameReport,
                                    QueryRecord, interval_overlap_s)
 from repro.obs.metrics import MetricsRegistry, default_registry
 from repro.obs.serialize import report_asdict, report_to_json, to_jsonable
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, host_span
 
 __all__ = [
     "AttributionLog",
@@ -34,6 +37,7 @@ __all__ = [
     "QueryRecord",
     "Tracer",
     "default_registry",
+    "host_span",
     "interval_overlap_s",
     "report_asdict",
     "report_to_json",

@@ -21,11 +21,22 @@ so a run loads directly in Perfetto / chrome://tracing. Conventions:
 
 Timestamps are microseconds (the format's unit); virtual seconds are
 multiplied by 1e6 on the way in.
+
+`host_span` is the wall-clock counterpart for the real-time request path:
+a `jax.profiler.TraceAnnotation`, which lands in the profiler's host plane
+on the device trace's clock (its keyword args become event stats), plus
+the span's duration observed into a `MetricsRegistry` histogram. With no
+profiler running the annotation costs a couple of microseconds.
 """
 from __future__ import annotations
 
+import contextlib
 import json
-from typing import Any, Dict, List, Optional
+import time
+from dataclasses import dataclass
+from typing import Any, Dict, Iterator, List, Optional
+
+import jax
 
 
 class Tracer:
@@ -129,3 +140,39 @@ class Tracer:
             json.dump(self.to_chrome_json(), f)
             f.write("\n")
         return path
+
+
+@dataclass
+class HostSpan:
+    """The host-clock reads (`time.perf_counter` seconds) of one
+    `host_span`; `t1` is set when the span closes."""
+
+    name: str
+    t0: float = 0.0
+    t1: float = 0.0
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+@contextlib.contextmanager
+def host_span(name: str, metrics=None, **args) -> Iterator[HostSpan]:
+    """Time the block on the host clock as profiler span `name`.
+
+    `args` are recorded as the span's stats in a profiler trace. With a
+    `MetricsRegistry`, a span that closes normally observes its duration
+    in ms into the histogram named after it, dots as underscores
+    (``serve.dispatch`` -> ``serve_dispatch_ms``). Yields the `HostSpan`
+    whose clock reads callers may reuse instead of reading the clock again.
+    """
+    span = HostSpan(name)
+    with jax.profiler.TraceAnnotation(name, **args):
+        span.t0 = time.perf_counter()
+        try:
+            yield span
+        finally:
+            span.t1 = time.perf_counter()
+    if metrics is not None:
+        metrics.histogram(name.replace(".", "_") + "_ms").observe(
+            span.seconds * 1e3)

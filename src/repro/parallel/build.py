@@ -23,6 +23,14 @@ through the exchange's batch-chunked path (memory stays chunk-bounded);
 AdaGrad's accumulator must see the full batch's row multiset at once, so
 its flat grads are concatenated and applied in one update.
 
+Named scopes: the stages run under `jax.named_scope`s that XLA keeps in
+every op's metadata (`op_name`), so a device trace's ops map back to a
+layer: ``dlrm.sparse`` (the exchange's forward, fused or composed),
+``dlrm.bottom_mlp`` / ``dlrm.interaction`` / ``dlrm.top_mlp`` (the dense
+model, forward and backward), ``dlrm.sparse_update`` (sparse grad routing
+and the table update) and ``dlrm.dense_update`` (the dense all-reduce and
+SGD). They are metadata only: the compiled program is otherwise the same.
+
 Dense-grad compression (`compress_grads=True`): the dense all-reduce stage
 runs the int8 block-quantized compressor (`optim/compression.py`) with
 persistent per-device error-feedback state carried in the opt state
@@ -292,6 +300,10 @@ def build_step(
     def _pick_tables(params):
         return {k: params[k] for k in exch.table_keys}
 
+    def _sparse_forward(tables, indices):
+        with jax.named_scope("dlrm.sparse"):
+            return exch.forward(tables, indices)
+
     # ---------------- serve: forward pipeline + sigmoid -------------------
     if mode == "serve":
         use_fused = bool(fused) and exch.supports_fused_forward()
@@ -307,9 +319,14 @@ def build_step(
                 den_mb = _mb_slices(dense, depth)
                 outs = []
                 for i in range(depth):
-                    bot = dlrm_lib.mlp_forward(params["bot_mlp"], den_mb[i])
-                    z = exch.fused_forward(tables, bot, idx_mb[i])
-                    logits = dlrm_lib.mlp_forward(params["top_mlp"], z)[:, 0]
+                    with jax.named_scope("dlrm.bottom_mlp"):
+                        bot = dlrm_lib.mlp_forward(params["bot_mlp"],
+                                                   den_mb[i])
+                    with jax.named_scope("dlrm.sparse"):
+                        z = exch.fused_forward(tables, bot, idx_mb[i])
+                    with jax.named_scope("dlrm.top_mlp"):
+                        logits = dlrm_lib.mlp_forward(params["top_mlp"],
+                                                      z)[:, 0]
                     outs.append(jax.nn.sigmoid(logits))
                 return (outs[0] if depth == 1
                         else jnp.concatenate(outs, axis=0))
@@ -319,13 +336,13 @@ def build_step(
                 idx_mb = _mb_slices(indices, depth)
                 den_mb = _mb_slices(dense, depth)
                 outs = []
-                nxt = exch.forward(tables, idx_mb[0])
+                nxt = _sparse_forward(tables, idx_mb[0])
                 for i in range(depth):
                     pooled_i, _ = nxt
                     if i + 1 < depth:
                         # issue the NEXT micro-batch's exchange before this
                         # micro-batch's MLP compute — the overlap window
-                        nxt = exch.forward(tables, idx_mb[i + 1])
+                        nxt = _sparse_forward(tables, idx_mb[i + 1])
                     logits = dlrm_lib.dlrm_forward_from_pooled(
                         params, den_mb[i], pooled_i)
                     outs.append(jax.nn.sigmoid(logits))
@@ -375,57 +392,63 @@ def build_step(
         loss = 0.0
         g_dense = None
         flat_mbs = []
-        nxt = exch.forward(tables, idx_mb[0])
+        nxt = _sparse_forward(tables, idx_mb[0])
         for i in range(depth):
             pooled_i, ctx_i = nxt
             if i + 1 < depth:
                 # exchange for micro-batch i+1 issued BEFORE compute of i
-                nxt = exch.forward(tables, idx_mb[i + 1])
+                nxt = _sparse_forward(tables, idx_mb[i + 1])
             loss_i, (g_i, gp_i) = jax.value_and_grad(
                 local_loss, argnums=(0, 1))(
                     dense_params, pooled_i, den_mb[i], lab_mb[i])
             loss = loss + loss_i
             g_dense = g_i if g_dense is None else _tree_add(g_dense, g_i)
             # grad routing for micro-batch i overlaps compute of i+1
-            if optimizer == "sgd":
-                new_tables = exch.sparse_apply(new_tables, ctx_i, gp_i,
-                                               sgd_upd)
-            else:
-                flat_mbs.append(exch.expand_grads(tables, ctx_i, gp_i))
+            with jax.named_scope("dlrm.sparse_update"):
+                if optimizer == "sgd":
+                    new_tables = exch.sparse_apply(new_tables, ctx_i, gp_i,
+                                                   sgd_upd)
+                else:
+                    flat_mbs.append(exch.expand_grads(tables, ctx_i, gp_i))
 
         # ---- dense all-reduce stage (the ALLREDUCE phase) ----
-        if compress_grads:
-            ef = jax.tree_util.tree_map(lambda e: e[0], opt_state["ef"])
-            g_mean, new_ef = car_fn(g_dense, ef)
-            grads = jax.tree_util.tree_map(lambda g: g * n_full, g_mean)
-        else:
-            grads = jax.lax.psum(g_dense, full_axes)
+        with jax.named_scope("dlrm.dense_update"):
+            if compress_grads:
+                ef = jax.tree_util.tree_map(lambda e: e[0], opt_state["ef"])
+                g_mean, new_ef = car_fn(g_dense, ef)
+                grads = jax.tree_util.tree_map(lambda g: g * n_full, g_mean)
+            else:
+                grads = jax.lax.psum(g_dense, full_axes)
         loss = jax.lax.psum(loss, full_axes)
-        new_dense = jax.tree_util.tree_map(lambda p, g: p - lr * g,
-                                           dense_params, grads)
+        with jax.named_scope("dlrm.dense_update"):
+            new_dense = jax.tree_util.tree_map(lambda p, g: p - lr * g,
+                                               dense_params, grads)
 
         # ---- sparse update stage (the SPARSE UPDT phase) ----
         # (SGD already applied per micro-batch above.)
         new_opt: Params = {}
-        if optimizer != "sgd":
-            ada = adagrad_row_update(lr)
-            for k in exch.table_keys:
-                new_opt[acc_key(k)] = opt_state[acc_key(k)]
-            for k, (fi, fg) in _concat_flat_grads(flat_mbs).items():
-                new_tables[k], new_opt[acc_key(k)] = ada(
-                    tables[k], opt_state[acc_key(k)], fi, fg)
-
-        if dp_axes:
-            # replicated (fast-tier) tables: sum the sparse deltas across the
-            # pure-DP replicas so every replica applies the full-batch update.
-            for k in exch.table_keys:
-                new_tables[k] = tables[k] + jax.lax.psum(
-                    new_tables[k] - tables[k], dp_axes)
+        with jax.named_scope("dlrm.sparse_update"):
             if optimizer != "sgd":
+                ada = adagrad_row_update(lr)
                 for k in exch.table_keys:
-                    ak = acc_key(k)
-                    a0 = opt_state[ak]
-                    new_opt[ak] = a0 + jax.lax.psum(new_opt[ak] - a0, dp_axes)
+                    new_opt[acc_key(k)] = opt_state[acc_key(k)]
+                for k, (fi, fg) in _concat_flat_grads(flat_mbs).items():
+                    new_tables[k], new_opt[acc_key(k)] = ada(
+                        tables[k], opt_state[acc_key(k)], fi, fg)
+
+            if dp_axes:
+                # replicated (fast-tier) tables: sum the sparse deltas across
+                # the pure-DP replicas so every replica applies the
+                # full-batch update.
+                for k in exch.table_keys:
+                    new_tables[k] = tables[k] + jax.lax.psum(
+                        new_tables[k] - tables[k], dp_axes)
+                if optimizer != "sgd":
+                    for k in exch.table_keys:
+                        ak = acc_key(k)
+                        a0 = opt_state[ak]
+                        new_opt[ak] = a0 + jax.lax.psum(new_opt[ak] - a0,
+                                                        dp_axes)
 
         if compress_grads:
             new_opt["ef"] = jax.tree_util.tree_map(lambda e: e[None], new_ef)
