@@ -69,6 +69,6 @@ def cached_embedding_bag_pallas(fast: jax.Array, bulk: jax.Array,
         out_specs=pl.BlockSpec((None, T, d), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), jnp.float32),
         scratch_shapes=line_scratch(
-            [(pf * d, fast.dtype), (pb * d, bulk.dtype)], L),
+            [(pf, fast.dtype), (pb, bulk.dtype)], L, d),
         interpret=interpret,
     )(flat_indices(fast_idx), flat_indices(bulk_idx), fast_l, bulk_l)

@@ -13,8 +13,20 @@ Grid: one step per sample. The step's ``T*L`` indices arrive as their own
 SMEM block (``(1, T*L)`` int32, 12.8 KB at RM2's T=40, L=80), so SMEM holds
 one sample's indices whatever the batch. Within a step the tables are
 walked in order with two VMEM line buffers: the DMAs for table t+1 are in
-flight while table t is pooled. Rows are added in L order, one at a time,
-into an fp32 accumulator.
+flight while table t is pooled, and one wait for the bytes of all L lines
+ends each table's DMAs.
+
+A table is pooled with whole-vreg operations. Its buffer is
+``(p, L, p*d)``: lookup l of row r lands in cell ``[r % p, l]``, and every
+other cell is zero (the buffer is zeroed before the table's DMAs start).
+Keeping plane q's lane group ``[q*d, (q+1)*d)`` and folding the p groups
+onto lanes ``[0, d)`` with rotations leaves lookup l's row in line l, and
+one fp32 sublane sum over the L lines pools them. Each cell is written by
+one lookup only, so a row repeated in a bag, or two rows of one line, are
+each counted once per lookup. Numerics: fp32 sums as a sublane reduction,
+whose order depends on the lookups' positions l only, not on the rows'
+slots, lines or parts: the same L rows pool to the same bits through any
+table. Against a sum in L order the pool differs by rounding only.
 
 The same gather/pool routine (`gather_pool`) serves the cached and fused
 kernels (``cached_embedding_bag.py``, ``fused_serve.py``).
@@ -75,27 +87,38 @@ def flat_indices(idx: jax.Array) -> jax.Array:
     return idx.reshape(B, 1, T * L)
 
 
-def line_scratch(parts_wd: Sequence[tuple], L: int):
-    """Two L-line VMEM buffers per part (``(width, dtype)`` each) and one
-    DMA semaphore per buffer slot."""
-    return ([pltpu.VMEM((2, L, w), dt) for w, dt in parts_wd]
+def line_scratch(parts_pd: Sequence[tuple], L: int, d: int):
+    """Two ``(p, L, p*d)`` VMEM line buffers per part (``(p, dtype)``
+    each) and one DMA semaphore per buffer slot."""
+    return ([pltpu.VMEM((2, p, L, p * d), dt) for p, dt in parts_pd]
             + [pltpu.SemaphoreType.DMA((2,))])
 
 
-def _row(line: jax.Array, slot, d: int, p: int) -> jax.Array:
-    """(1, p*d) fp32 line -> (1, d) row in lanes [slot*d, slot*d + d).
+def _rows(buf, slot, d: int) -> jax.Array:
+    """``buf[slot]``, (p, L, p*d) slot-placed lines -> (L, p*d) fp32 with
+    lookup l's row in lanes [0, d).
 
-    The other rows' lanes are zeroed and the p lane groups folded onto
-    lanes [0, d) with rotations; exactly one group is non-zero, so the
-    fold adds exact zeros."""
+    Plane q holds the lines of the lookups whose row sits in slot q, zero
+    elsewhere, so keeping each plane's own lane group leaves every line
+    with its one row; the p groups then fold onto lanes [0, d) with
+    rotations, adding exact zeros."""
+    p = buf.shape[1]
+    rows = buf[slot, 0].astype(jnp.float32)
     if p == 1:
-        return line
-    group = jax.lax.broadcasted_iota(jnp.int32, line.shape, 1) // d
-    m = jnp.where(group == slot, line, 0.0)
-    folded = m
+        return rows
+    group = jax.lax.broadcasted_iota(jnp.int32, (1, p * d), 1) // d
     for q in range(1, p):
-        folded = folded + pltpu.roll(m, shift=q * d, axis=1)
-    return folded[:, :d]
+        rows = jnp.where(group == q, buf[slot, q].astype(jnp.float32), rows)
+    folded = rows
+    for q in range(1, p):
+        folded = folded + pltpu.roll(rows, shift=q * d, axis=1)
+    return folded
+
+
+# DMA starts issued per loop step: the scalar work of issuing a lookup's
+# line (an SMEM read, a shift, a mask, the descriptor) is the kernel's floor,
+# and a rolled loop adds its own branch and counter to each one.
+_UNROLL = 8
 
 
 def gather_pool(parts: Sequence[Part], bufs, sem, *, T: int, L: int, d: int,
@@ -104,56 +127,60 @@ def gather_pool(parts: Sequence[Part], bufs, sem, *, T: int, L: int, d: int,
 
     Either every part serves every table (the cached layout: one row per
     part, summed, exactly one of them a zero pad), or the parts split the
-    tables between them (the grouped layout)."""
-    summed = all(pt.lo == 0 and pt.hi == T for pt in parts)
+    tables between them (the grouped layout). Either way the parts' pools
+    add up: a part that serves no lookup of a table leaves its buffer as
+    zeroed."""
+
+    serves_all = [pt.lo == 0 and pt.hi == T for pt in parts]
 
     def each_part(t, fn):
         for k, pt in enumerate(parts):
-            if pt.lo == 0 and pt.hi == T:
+            if serves_all[k]:
                 fn(k, pt)
             else:
                 pl.when(jnp.logical_and(t >= pt.lo, t < pt.hi))(
                     functools.partial(fn, k, pt))
 
     def start(t, slot):
+        # every cell a lookup does not write has to read zero; with one
+        # slot per line and every table served, each lookup writes its cell
+        for k, pt in enumerate(parts):
+            if pt.p > 1 or not serves_all[k]:
+                bufs[k][slot] = jnp.zeros(bufs[k].shape[1:], bufs[k].dtype)
+
         def one(k, pt):
-            def body(l, c):
+            shift = pt.p.bit_length() - 1         # p is a power of two
+
+            def issue(l):
                 r = pt.idx[0, t * L + l]
                 pltpu.make_async_copy(
-                    pt.tab.at[t - pt.lo, pl.ds(r // pt.p, 1), :],
-                    bufs[k].at[slot, pl.ds(l, 1), :], sem.at[slot]).start()
+                    pt.tab.at[t - pt.lo, pl.ds(r >> shift, 1), :],
+                    bufs[k].at[slot, r & (pt.p - 1), pl.ds(l, 1), :],
+                    sem.at[slot]).start()
+
+            def unrolled(i, c):
+                for u in range(_UNROLL):
+                    issue(i * _UNROLL + u)
                 return c
-            jax.lax.fori_loop(0, L, body, 0)
+            jax.lax.fori_loop(0, L // _UNROLL, unrolled, 0)
+            for l in range(L - L % _UNROLL, L):
+                issue(l)
         each_part(t, one)
 
     def wait(t, slot):
+        # one wait for the bytes of all L lines of the table's plane
         def one(k, pt):
-            def body(l, c):
-                pltpu.make_async_copy(
-                    pt.tab.at[0, pl.ds(0, 1), :],
-                    bufs[k].at[slot, pl.ds(0, 1), :], sem.at[slot]).wait()
-                return c
-            jax.lax.fori_loop(0, L, body, 0)
+            plane = bufs[k].at[slot, 0]
+            pltpu.make_async_copy(plane, plane, sem.at[slot]).wait()
         each_part(t, one)
 
-    def pool(t, slot):
-        def body(l, acc):
-            rows = []
-            for k, pt in enumerate(parts):
-                r = pt.idx[0, t * L + l]
-                line = bufs[k][slot, pl.ds(l, 1), :].astype(jnp.float32)
-                rows.append(_row(line, r % pt.p, d, pt.p))
-            if summed:
-                row = rows[0]
-                for x in rows[1:]:
-                    row = row + x
-            else:
-                row = rows[-1]
-                for pt, x in zip(parts[-2::-1], rows[-2::-1]):
-                    row = jnp.where(t < pt.hi, x, row)
-            return acc + row
-        return jax.lax.fori_loop(0, L, body,
-                                 jnp.zeros((1, d), jnp.float32))
+    def pool(slot):
+        # the parts' rows add first, so the pool of a lookup's row is the
+        # same whichever part, slot or line it came from
+        rows = _rows(bufs[0], slot, d)[:, :d]
+        for buf in bufs[1:]:
+            rows = rows + _rows(buf, slot, d)[:, :d]
+        return rows.sum(axis=0, keepdims=True)
 
     start(0, 0)
 
@@ -165,7 +192,7 @@ def gather_pool(parts: Sequence[Part], bufs, sem, *, T: int, L: int, d: int,
             start(t + 1, 1 - slot)
 
         wait(t, slot)
-        emit(t, pool(t, slot))
+        emit(t, pool(slot))
         return c
 
     jax.lax.fori_loop(0, T, table, 0)
@@ -201,6 +228,6 @@ def embedding_bag_pallas(tables: jax.Array, indices: jax.Array,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((None, T, d), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, d), jnp.float32),
-        scratch_shapes=line_scratch([(p * d, tables.dtype)], L),
+        scratch_shapes=line_scratch([(p, tables.dtype)], L, d),
         interpret=interpret,
     )(flat_indices(indices), lines)

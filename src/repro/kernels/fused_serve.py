@@ -38,11 +38,11 @@ un-permutation for the grouped variant rides the same gather: with
 so gathering ``f[:, pos[li], pos[lj]]`` at the ORIGINAL-order tril indices
 restores original table order for free.
 
-Numerics: identical accumulation order to the composed kernels — rows sum
-into the pool in L order (fp32), then one fp32 ``dot_general``. Against the
-composed REFERENCE path the results are bit-identical on equal dtypes; a
-bf16-table pool could differ by 1 ulp from a differently-blocked composed
-schedule (the PR 5/7 allclose caveat), which the tests pin down.
+Numerics: the same pool as the composed bag kernels (``gather_pool``): a
+table's rows sum in fp32 as a sublane reduction over its L slot-placed
+lines, then one fp32 ``dot_general``. Against the composed REFERENCE path
+(an fp32 sum in L order) the results differ by rounding only, which the
+tests bound at 1e-5.
 """
 from __future__ import annotations
 
@@ -166,7 +166,7 @@ def fused_bag_interactions_pallas(tables: jax.Array, indices: jax.Array,
     f = _fused_call(
         functools.partial(_fused_bag_kernel, bb=bb, T=T, L=L, d=d, p=p),
         bb=bb, Bp=Bp, T=T, L=L, d=d, s1=s1, n_idx=1, n_tab=1,
-        scratch=line_scratch([(p * d, tables.dtype)], L),
+        scratch=line_scratch([(p, tables.dtype)], L, d),
         interpret=interpret)(flat_indices(idx_p), bot_p, lines)
     return _finalize(bot_out, f)
 
@@ -213,8 +213,8 @@ def fused_cached_bag_interactions_pallas(
         functools.partial(_fused_cached_kernel, bb=bb, T=T, L=L, d=d,
                           pf=pf, pb=pb),
         bb=bb, Bp=Bp, T=T, L=L, d=d, s1=s1, n_idx=2, n_tab=2,
-        scratch=line_scratch([(pf * d, fast.dtype), (pb * d, bulk.dtype)],
-                             L),
+        scratch=line_scratch([(pf, fast.dtype), (pb, bulk.dtype)], L,
+                             d),
         interpret=interpret)(flat_indices(fi_p), flat_indices(bi_p), bot_p,
                              fast_l, bulk_l)
     return _finalize(bot_out, f)
@@ -278,7 +278,7 @@ def fused_grouped_bag_interactions_pallas(
         functools.partial(_fused_grouped_kernel, bb=bb, T=T, L=L, d=d,
                           n_fast=Tf, pf=pf, pb=pb),
         bb=bb, Bp=Bp, T=T, L=L, d=d, s1=s1, n_idx=1, n_tab=2,
-        scratch=line_scratch([(pf * d, tables_fast.dtype),
-                              (pb * d, tables_bulk.dtype)], L),
+        scratch=line_scratch([(pf, tables_fast.dtype),
+                              (pb, tables_bulk.dtype)], L, d),
         interpret=interpret)(flat_indices(idx_p), bot_p, fast_l, bulk_l)
     return _finalize(bot_out, f, inv_perm=inv_perm)

@@ -38,6 +38,8 @@ def _inputs(key, B, T, L, R, d):
     (4, 1, 5, 32, 16, 4),    # single table
     (3, 2, 2, 8, 8, 64),     # block_b > B: clamps to B
     (8, 5, 3, 24, 16, 4),    # exact blocking
+    (5, 3, 13, 64, 32, 4),   # d = 32, p = 4: L not a multiple of 8
+    (3, 2, 5, 16, 128, 2),   # d = 128: one row per line (p = 1)
 ])
 def test_fused_matches_composed(B, T, L, R, d, bb):
     tables, idx, bot = _inputs(jax.random.PRNGKey(B * 10 + T), B, T, L, R, d)
@@ -48,15 +50,32 @@ def test_fused_matches_composed(B, T, L, R, d, bb):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("bag", [
+    [8, 9, 10, 11, 9, 8],     # every slot of line 2, two of them twice
+    [13] * 9,                 # one row, every lookup
+])
+def test_fused_pools_rows_sharing_a_line(bag):
+    B, T, R, d = 3, 2, 16, 32                      # p = 4 rows a line
+    tables, _, bot = _inputs(jax.random.PRNGKey(7), B, T, len(bag), R, d)
+    idx = jnp.broadcast_to(jnp.asarray(bag, jnp.int32), (B, T, len(bag)))
+    got = fused_bag_interactions_pallas(tables, idx, bot, block_b=2,
+                                        interpret=True)
+    want = ref.fused_bag_interactions_ref(tables, idx, bot)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 # -------------------------------------------------- two-tier (cached) kernel
-def _pack_two_tier(tables, idx, hot_fraction, key):
+def _pack_two_tier(tables, idx, hot_fraction, key, line_rows=1):
     """cached_embedding_bag layout: hot rows packed into a fast tier with
-    zeros miss slot S; bulk keeps every row plus a zeros hit slot R."""
+    zeros miss slot S; bulk keeps every row plus a zeros hit slot R. The
+    fast tier's row count is padded with zero rows to a multiple of
+    ``line_rows``."""
     T, R, d = tables.shape
     hot = np.asarray(jax.random.bernoulli(key, hot_fraction, (T, R)))
     tabs = np.asarray(tables)
     S = max(int(hot.sum(axis=1).max()), 1)
-    fast = np.zeros((T, S + 1, d), np.float32)
+    S1 = -(-(S + 1) // line_rows) * line_rows
+    fast = np.zeros((T, S1, d), np.float32)
     slot = np.full((T, R), S, np.int32)
     for t in range(T):
         rows = np.flatnonzero(hot[t])
@@ -70,12 +89,18 @@ def _pack_two_tier(tables, idx, hot_fraction, key):
     return jnp.asarray(fast), jnp.asarray(bulk), fi, bi
 
 
-@pytest.mark.parametrize("hot_fraction", [0.0, 0.1, 1.0])
-def test_fused_cached_matches_composed(hot_fraction):
-    B, T, L, R, d = 5, 3, 4, 16, 8
+@pytest.mark.parametrize("hot_fraction,L,R,d,line_rows", [
+    (0.0, 4, 16, 8, 1),
+    (0.1, 4, 16, 8, 1),
+    (1.0, 4, 16, 8, 1),
+    (0.5, 13, 63, 32, 4),     # both tiers 4 rows a line, L % 8 != 0
+])
+def test_fused_cached_matches_composed(hot_fraction, L, R, d, line_rows):
+    B, T = 5, 3
     tables, idx, bot = _inputs(jax.random.PRNGKey(17), B, T, L, R, d)
     fast, bulk, fi, bi = _pack_two_tier(tables, idx, hot_fraction,
-                                        jax.random.PRNGKey(18))
+                                        jax.random.PRNGKey(18),
+                                        line_rows=line_rows)
     got = fused_cached_bag_interactions_pallas(fast, bulk, fi, bi, bot,
                                                block_b=4, interpret=True)
     want = ref.fused_bag_interactions_ref(tables, idx, bot)
@@ -120,11 +145,15 @@ def test_fused_grouped_empty_group_delegates(fast_ids, bulk_ids):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-def test_fused_grouped_matches_grouped_ref():
+@pytest.mark.parametrize("L,R,d", [
+    (3, 12, 8),
+    (13, 16, 32),             # p = 4 in both groups, L % 8 != 0
+])
+def test_fused_grouped_matches_grouped_ref(L, R, d):
     from repro.parallel.plan import PlanGroups
 
     groups = PlanGroups((1, 2), (0, 3))
-    B, L, R, d = 6, 3, 12, 8
+    B = 6
     tables, idx, bot = _inputs(jax.random.PRNGKey(5), B, 4, L, R, d)
     perm = np.asarray(groups.fast_ids + groups.bulk_ids, np.int32)
     tf = tables[jnp.asarray(groups.fast_ids, jnp.int32)]

@@ -15,9 +15,10 @@ from repro.kernels.interactions import interactions_pallas
 # ---------------------------------------------------------------- embedding
 @pytest.mark.parametrize("B,T,L,R,d", [
     (4, 8, 16, 64, 32),
-    (2, 3, 5, 32, 128),
+    (2, 3, 5, 32, 128),           # d = 128: one row per line (p = 1)
     (1, 1, 1, 8, 8),
     (8, 40, 8, 128, 64),          # RM2-shaped (reduced L)
+    (3, 4, 13, 64, 32),           # p = 4, L not a multiple of 8
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_embedding_bag_matches_ref(B, T, L, R, d, dtype):
@@ -30,18 +31,28 @@ def test_embedding_bag_matches_ref(B, T, L, R, d, dtype):
     np.testing.assert_allclose(out, expect, rtol=tol, atol=tol)
 
 
-def test_embedding_bag_repeated_indices():
-    """Pooling must count duplicates (sum, not set semantics)."""
-    tables = jnp.arange(12, dtype=jnp.float32).reshape(1, 3, 4)
-    idx = jnp.array([[[1, 1, 1]]])                       # row 1 three times
+@pytest.mark.parametrize("R,d,bag", [
+    (3, 4, [1, 1, 1]),                 # row 1 three times, a row per line
+    (8, 32, [5] * 11),                 # p = 4: one row, every lookup
+    (8, 32, [4, 5, 6, 7]),             # all four slots of one line
+    (8, 32, [6, 4, 6, 7, 5, 6, 0, 4, 7]),  # one line's slots, repeated
+    (2, 128, [1, 0, 1, 1, 1]),         # p = 1
+])
+def test_embedding_bag_repeated_indices(R, d, bag):
+    """Pooling must count duplicates (sum, not set semantics), also where
+    rows share a line."""
+    tables = jnp.arange(R * d, dtype=jnp.float32).reshape(1, R, d)
+    idx = jnp.array([[bag]])
     out = embedding_bag_pallas(tables, idx)
-    np.testing.assert_allclose(out[0, 0], 3 * tables[0, 1])
+    np.testing.assert_allclose(out[0, 0], sum(tables[0, r] for r in bag))
 
 
 # ------------------------------------------------------- cached (tiered) bag
 @pytest.mark.parametrize("B,T,L,R,S,d", [
     (4, 3, 8, 64, 16, 32),
     (2, 1, 5, 32, 4, 16),
+    (3, 2, 13, 63, 15, 32),       # both tiers 4 rows a line, L % 8 != 0
+    (3, 2, 13, 64, 15, 32),       # fast tier 4 rows a line, bulk 1
 ])
 def test_cached_embedding_bag_matches_ref(B, T, L, R, S, d):
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(B + S), 3)
